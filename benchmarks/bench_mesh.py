@@ -28,9 +28,10 @@ from __future__ import annotations
 
 import argparse
 import os
-import subprocess
 import sys
 import time
+
+from .cpu_child import spawn_inner
 
 HEADER = ("bench,workload,batch,shards,mode,rounds,items,elapsed_s,"
           "rounds_per_s,items_per_s,host_syncs,drained,"
@@ -39,24 +40,10 @@ TRIALS = 3
 
 
 def _spawn_inner(args, out) -> int:
-    """Run this module in a subprocess with the mesh device count forced;
-    relay its stdout into ``out``."""
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    env["XLA_FLAGS"] = (f"{flags} --xla_force_host_platform_device_count="
-                        f"{args[args.index('--shards') + 1]}").strip()
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(repo, "src"), env.get("PYTHONPATH"), repo)
-        if p)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_mesh", "--inner"] + args,
-        capture_output=True, text=True, cwd=repo, env=env, timeout=1800)
-    print(proc.stdout, end="", file=out)
-    if proc.returncode != 0:
-        print(f"# FAIL: inner benchmark exited {proc.returncode}: "
-              f"{proc.stderr[-2000:]}", file=out)
-    return proc.returncode
+    """Run this module's inner half on the requested number of
+    virtual CPU devices; relay its stdout into ``out``."""
+    return spawn_inner("benchmarks.bench_mesh", args, out,
+                       devices=int(args[args.index('--shards') + 1]))
 
 
 # ---------------------------------------------------------------------------

@@ -38,6 +38,8 @@ import subprocess
 import sys
 import time
 
+from .cpu_child import REPO, cpu_child_env
+
 HEADER = ("bench,workload,batch,telemetry,rounds,items,elapsed_s,"
           "rounds_per_s,items_per_s,overhead_pct,records,dropped")
 TRIALS = 30     # interleaved on/off; the estimator is the MIN over trials,
@@ -312,10 +314,10 @@ def smoke(out=sys.stdout) -> bool:
                     spans=sp)
         write_chrome_trace(ch, tel.records, tel.sync_points,
                            engine="fanout", flows=sp.flows)
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         res = subprocess.run(
-            [sys.executable, os.path.join(repo, "tools", "trace_check.py"),
-             jl, "--chrome", ch], capture_output=True, text=True)
+            [sys.executable, os.path.join(REPO, "tools", "trace_check.py"),
+             jl, "--chrome", ch], capture_output=True, text=True,
+            env=cpu_child_env())
         if res.returncode != 0:
             print(f"# FAIL: trace_check rejected the export: "
                   f"{res.stderr[-1000:]}", file=out)
@@ -334,21 +336,14 @@ def trace_main(out=sys.stdout, *, trace_dir: str = ".", shards: int = 2,
     """Emit the PR-6 acceptance artifact: one mesh SSSP run's telemetry as
     ``trace_sssp.jsonl`` + ``trace_sssp.json`` (Chrome) under
     ``trace_dir``, validated by ``tools/trace_check.py``."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     trace_dir = os.path.abspath(trace_dir)
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    env["XLA_FLAGS"] = (f"{flags} --xla_force_host_platform_device_count="
-                        f"{shards}").strip()
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(repo, "src"), env.get("PYTHONPATH"), repo)
-        if p)
     os.makedirs(trace_dir, exist_ok=True)
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_obs", "--inner-trace",
          "--trace-dir", trace_dir, "--shards", str(shards),
          "--batches", str(batch), "--n", str(n)],
-        capture_output=True, text=True, cwd=repo, env=env, timeout=1800)
+        capture_output=True, text=True, cwd=REPO,
+        env=cpu_child_env(shards), timeout=1800)
     print(proc.stdout, end="", file=out)
     if proc.returncode != 0:
         print(f"# FAIL: trace subprocess exited {proc.returncode}: "
@@ -357,8 +352,9 @@ def trace_main(out=sys.stdout, *, trace_dir: str = ".", shards: int = 2,
     jl = os.path.join(trace_dir, "trace_sssp.jsonl")
     ch = os.path.join(trace_dir, "trace_sssp.json")
     res = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "trace_check.py"),
-         jl, "--chrome", ch], capture_output=True, text=True)
+        [sys.executable, os.path.join(REPO, "tools", "trace_check.py"),
+         jl, "--chrome", ch], capture_output=True, text=True,
+        env=cpu_child_env())
     print(f"# {res.stdout.strip()}", file=out)
     if res.returncode != 0:
         print(f"# FAIL: emitted trace is schema-invalid: "

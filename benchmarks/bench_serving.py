@@ -37,29 +37,15 @@ import sys
 import tempfile
 import time
 
+from .cpu_child import REPO, cpu_child_env, spawn_inner
+
 HEADER = ("bench,mode,shards,rate,offered_load,tenants,tenant,submitted,"
           "admitted,completed,goodput,slo_ticks,p50_lat,p99_lat,ticks,"
           "elapsed_s,ticks_per_s")
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 def _spawn_inner(args, out) -> int:
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    env["XLA_FLAGS"] = (f"{flags} --xla_force_host_platform_device_count=2"
-                        ).strip()
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(REPO, "src"), env.get("PYTHONPATH"), REPO)
-        if p)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_serving", "--inner"] + args,
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=1800)
-    print(proc.stdout, end="", file=out)
-    if proc.returncode != 0:
-        print(f"# FAIL: inner benchmark exited {proc.returncode}: "
-              f"{proc.stderr[-2000:]}", file=out)
-    return proc.returncode
+    return spawn_inner("benchmarks.bench_serving", args, out, devices=2)
 
 
 # ---------------------------------------------------------------------------
@@ -269,7 +255,8 @@ def inner_smoke(out) -> bool:
                     metrics=dict(eng.stats), engine="serving")
         proc = subprocess.run(
             [sys.executable, os.path.join(REPO, "tools", "trace_check.py"),
-             path], capture_output=True, text=True, cwd=REPO, timeout=300)
+             path], capture_output=True, text=True, cwd=REPO,
+            env=cpu_child_env(), timeout=300)
         print(f"# trace_check: {proc.stdout.strip()}", file=out)
         if proc.returncode != 0:
             print(f"# FAIL: serving trace failed schema validation: "

@@ -45,15 +45,13 @@ def road_like(n: int, seed: int = 0) -> CSRGraph:
     """Grid-ish graph: low avg degree, long diameter (road_usa family)."""
     side = int(np.sqrt(n))
     n = side * side
-    rows, cols = [], []
-    for v in range(n):
-        r, c = divmod(v, side)
-        for dr, dc in ((0, 1), (1, 0), (0, -1), (-1, 0)):
-            rr, cc = r + dr, c + dc
-            if 0 <= rr < side and 0 <= cc < side:
-                rows.append(v)
-                cols.append(rr * side + cc)
-    return _to_csr(n, rows, cols, f"road_{n}")
+    r, c = np.divmod(np.arange(n, dtype=np.int64), side)
+    # (n, 4) neighbour table in (right, down, left, up) order per vertex
+    rr = r[:, None] + np.array([0, 1, 0, -1])
+    cc = c[:, None] + np.array([1, 0, -1, 0])
+    ok = (rr >= 0) & (rr < side) & (cc >= 0) & (cc < side)
+    rows = np.broadcast_to(np.arange(n)[:, None], ok.shape)[ok]
+    return _to_csr(n, rows, (rr * side + cc)[ok], f"road_{n}")
 
 
 def kron_like(n: int, avg_deg: int = 16, seed: int = 0) -> CSRGraph:
@@ -207,6 +205,28 @@ def bfs_runtime(g: CSRGraph, source: int = 0, *, algo: str = "glfq",
     return dist, info
 
 
+def neighbour_table(g: CSRGraph, values=None, fill: int = -1):
+    """Out-neighbour rows of ``g`` as a flat ``(n * fan,)`` device table,
+    ``fan`` the max out-degree: row ``v`` holds ``values`` (default the
+    column ids) of v's edges in CSR order, padded with ``fill``.  Flat,
+    not ``(n, fan)``: the TPU tiles a 2-D array's minor dimension to 128
+    lanes, so a 4-wide table would sit 32 times over in the program's
+    constants.  Returns ``(table, fan)``; ``table_rows`` reads it."""
+    n = g.n
+    deg = np.diff(g.row_ptr).astype(np.int64)
+    fan = max(int(deg.max()) if n else 0, 1)
+    rows = np.repeat(np.arange(n, dtype=np.int64), deg)
+    pos = np.arange(g.m) - np.repeat(g.row_ptr[:-1].astype(np.int64), deg)
+    table = np.full(n * fan, fill, np.int32)
+    table[rows * fan + pos] = g.col_idx if values is None else values
+    return jnp.asarray(table), fan
+
+
+def table_rows(table, v, fan: int):
+    """Rows ``v`` (B,) of a ``neighbour_table``, as a (B, fan) block."""
+    return table[v[:, None] * fan + jnp.arange(fan, dtype=jnp.int32)]
+
+
 def bfs_rounds_runner(g: CSRGraph, *, batch: int = 64, fused: bool = True,
                       interpret=None, sync_every: int = 0, telemetry=None,
                       compact=None):
@@ -217,19 +237,13 @@ def bfs_rounds_runner(g: CSRGraph, *, batch: int = 64, fused: bool = True,
     from ..runtime import RoundRunner
 
     n = g.n
-    deg = np.diff(g.row_ptr).astype(np.int64)
-    fan = max(int(deg.max()) if n else 0, 1)
-    nbr = np.full((n, fan), -1, np.int32)
-    rows = np.repeat(np.arange(n), deg)
-    pos = np.arange(g.m) - np.repeat(g.row_ptr[:-1].astype(np.int64), deg)
-    nbr[rows, pos] = g.col_idx
-    nbr_j = jnp.asarray(nbr)
+    nbr, fan = neighbour_table(g)
     big = np.iinfo(np.int32).max
 
     def step(dist, vals, valid):
         v = jnp.where(valid, vals, 0)
         dv = jnp.where(valid, dist[v], 0)
-        w = jnp.where(valid[:, None], nbr_j[v], -1)          # (B, F)
+        w = jnp.where(valid[:, None], table_rows(nbr, v, fan), -1)  # (B, F)
         wc = jnp.clip(w, 0, n - 1)
         eligible = (w >= 0) & (dist[wc] < 0)
         b, f = w.shape
@@ -307,18 +321,12 @@ def bfs_mesh_rounds_runner(g: CSRGraph, *, mesh=None, shards: int = None,
     if n * (n + 2) >= 2 ** 31:
         raise ValueError(f"graph too large for packed (d, v) payloads: "
                          f"n={n} needs n*(n+2) < 2^31")
-    deg = np.diff(g.row_ptr).astype(np.int64)
-    fan = max(int(deg.max()) if n else 0, 1)
+    nbr, fan = neighbour_table(g)
     # the in-batch winner key is nd·(batch·fan) + order, nd ≤ n
     if (n + 1) * batch * fan >= 2 ** 31:
         raise ValueError(f"batch {batch} x max degree {fan} too wide for "
                          f"int32 winner keys on n={n}: needs "
                          f"(n+1)*batch*fan < 2^31")
-    nbr = np.full((n, fan), -1, np.int32)
-    rows = np.repeat(np.arange(n), deg)
-    pos = np.arange(g.m) - np.repeat(g.row_ptr[:-1].astype(np.int64), deg)
-    nbr[rows, pos] = g.col_idx
-    nbr_j = jnp.asarray(nbr)
     big = np.iinfo(np.int32).max
 
     def step(dist, vals, valid):
@@ -331,7 +339,7 @@ def bfs_mesh_rounds_runner(g: CSRGraph, *, mesh=None, shards: int = None,
         # children, which keeps the recursion finite)
         fresh = valid & (d <= dist[v])
         dist = dist.at[jnp.where(fresh, v, n)].min(d, mode="drop")
-        w = jnp.where(fresh[:, None], nbr_j[v], -1)    # (B, F)
+        w = jnp.where(fresh[:, None], table_rows(nbr, v, fan), -1)  # (B, F)
         wc = jnp.clip(w, 0, n - 1)
         nd = jnp.broadcast_to((d + 1)[:, None], w.shape)
         elig = (w >= 0) & (nd < dist[wc])
@@ -387,16 +395,21 @@ def bfs_mesh_rounds(g: CSRGraph, source: int = 0, *, mesh=None,
 
 
 def bfs_reference(g: CSRGraph, source: int = 0) -> np.ndarray:
-    """Plain numpy BFS oracle."""
-    from collections import deque
+    """Plain numpy BFS oracle: level-synchronous frontier sweeps over the
+    CSR arrays (-1 marks unreachable vertices)."""
     dist = np.full(g.n, -1, np.int32)
     dist[source] = 0
-    dq = deque([source])
-    while dq:
-        u = dq.popleft()
-        for k in range(g.row_ptr[u], g.row_ptr[u + 1]):
-            v = g.col_idx[k]
-            if dist[v] < 0:
-                dist[v] = dist[u] + 1
-                dq.append(v)
+    row_ptr = g.row_ptr.astype(np.int64)
+    frontier = np.array([source], np.int64)
+    level = 0
+    while frontier.size:
+        lo, hi = row_ptr[frontier], row_ptr[frontier + 1]
+        deg = hi - lo
+        # edge slots of every frontier vertex, concatenated
+        edges = (np.arange(deg.sum()) - np.repeat(np.cumsum(deg) - deg, deg)
+                 + np.repeat(lo, deg))
+        nbr = g.col_idx[edges]
+        frontier = np.unique(nbr[dist[nbr] < 0]).astype(np.int64)
+        level += 1
+        dist[frontier] = level
     return dist

@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .bfs import CSRGraph
+from .bfs import CSRGraph, neighbour_table, table_rows
 
 BIG = np.iinfo(np.int32).max
 
@@ -50,20 +50,22 @@ def with_weights(g: CSRGraph, max_w: int = 8, seed: int = 0) -> np.ndarray:
 def dijkstra_reference(g: CSRGraph, weights: np.ndarray,
                        source: int = 0) -> np.ndarray:
     """Plain heapq Dijkstra oracle; -1 marks unreachable vertices."""
-    dist = np.full(g.n, -1, np.int64)
+    row_ptr, col_idx = g.row_ptr.tolist(), g.col_idx.tolist()
+    weights = np.asarray(weights).tolist()
+    dist = [-1] * g.n
     dist[source] = 0
     pq = [(0, source)]
     while pq:
         d, u = heapq.heappop(pq)
         if d > dist[u]:
             continue
-        for k in range(g.row_ptr[u], g.row_ptr[u + 1]):
-            v = int(g.col_idx[k])
-            nd = d + int(weights[k])
+        for k in range(row_ptr[u], row_ptr[u + 1]):
+            v = col_idx[k]
+            nd = d + weights[k]
             if dist[v] < 0 or nd < dist[v]:
                 dist[v] = nd
                 heapq.heappush(pq, (nd, v))
-    return dist.astype(np.int32)
+    return np.asarray(dist, np.int64).astype(np.int32)
 
 
 def sssp_mesh_rounds_runner(g: CSRGraph, weights: np.ndarray, *, mesh=None,
@@ -120,16 +122,8 @@ def sssp_mesh_rounds_runner(g: CSRGraph, weights: np.ndarray, *, mesh=None,
             f"(use split_payload=True for the two-plane layout)")
     if delta < 1:
         raise ValueError(f"delta must be >= 1, got {delta}")
-    deg = np.diff(g.row_ptr).astype(np.int64)
-    fan = max(int(deg.max()) if n else 0, 1)
-    nbr = np.full((n, fan), -1, np.int32)
-    wgt = np.zeros((n, fan), np.int32)
-    rows = np.repeat(np.arange(n), deg)
-    pos = np.arange(g.m) - np.repeat(g.row_ptr[:-1].astype(np.int64), deg)
-    nbr[rows, pos] = g.col_idx
-    wgt[rows, pos] = weights
-    nbr_j = jnp.asarray(nbr)
-    wgt_j = jnp.asarray(wgt)
+    nbr, fan = neighbour_table(g)
+    wgt, _ = neighbour_table(g, weights, fill=0)
 
     def _relax(dist, v, d, valid):
         """Shared label-correcting core: claim (v, d) pairs in, winning
@@ -141,9 +135,9 @@ def sssp_mesh_rounds_runner(g: CSRGraph, weights: np.ndarray, *, mesh=None,
         # children, which keeps the recursion finite)
         fresh = valid & (d <= dist[v])
         dist = dist.at[jnp.where(fresh, v, n)].min(d, mode="drop")
-        w = jnp.where(fresh[:, None], nbr_j[v], -1)          # (B, F)
+        w = jnp.where(fresh[:, None], table_rows(nbr, v, fan), -1)  # (B, F)
         wc = jnp.clip(w, 0, n - 1)
-        nd = d[:, None] + wgt_j[v]
+        nd = d[:, None] + table_rows(wgt, v, fan)
         elig = (w >= 0) & (nd < dist[wc])
         # in-batch winner per target: smallest nd, then row-major order —
         # two scatter-mins, so no packed winner key to overflow

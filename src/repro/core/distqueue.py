@@ -40,8 +40,8 @@ explicit mask, never a sign test).
 
 Replication typing: payload exchange uses ``mesh_round_gather`` — a
 single psum that is bit-exact integer gather *and* replicated-typed, so
-the updated planes satisfy shard_map's replication checker and callers
-keep ``P()`` out_specs without ``check_rep=False``.  ``dist_claim_round``
+the updated planes satisfy shard_map's varying-manual-axes checker and
+callers keep ``P()`` out_specs with ``check_vma=True``.  ``dist_claim_round``
 needs no collective at all: the claim schedule is a pure function of the
 replicated head/tail.
 
@@ -67,7 +67,7 @@ import jax
 import jax.numpy as jnp
 
 from ..distributed.collectives import mesh_round_gather, mesh_ticket_base  # noqa: F401  (ticket base re-exported for callers)
-from ..jaxcompat import axis_size as _axis_size, pvary as _pvary
+from ..jaxcompat import pvary as _pvary
 from ..kernels.compact import compact_planes
 from ..kernels.heap_batch import KEY_INF
 from ..kernels.ring_slots import deq_planes, enq_planes
@@ -266,7 +266,7 @@ def dist_enqueue_round(state: DistQueueState, values: jax.Array,
                                 active, ranks, nslots_log2=lg, engine=engine)
     new_state = DistQueueState(*planes, tail=state.tail + total,
                                head=state.head)
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     ok_local = _pvary(ok, axis).reshape(n, b)[me]
     return new_state, (ok_local > 0) & (mask > 0)
@@ -286,7 +286,7 @@ def dist_dequeue_round(state: DistQueueState, want: jax.Array, axis: str, *,
                                       nslots_log2=lg, engine=engine)
     new_state = DistQueueState(*planes, tail=state.tail,
                                head=state.head + total)
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     vals_local = _pvary(vals, axis).reshape(n, b)[me]
     ok_local = _pvary(ok, axis).reshape(n, b)[me]
@@ -330,7 +330,7 @@ def dist_publish_round(state: DistQueueState, values: jax.Array,
     total = jnp.where(over, 0, total)
     new_state = DistQueueState(*planes, tail=state.tail + total,
                                head=state.head)
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     ok_local = _pvary(ok, axis).reshape(n, b)[me]
     granted = (ok_local > 0) & (mask > 0)
@@ -445,7 +445,7 @@ def dist_claim_round(state: DistQueueState, k, batch: int, axis: str, *,
     return tuple (-1 on missed lanes).  The stamp plane itself is
     read-only at claim time."""
     lg = _nslots_log2(state)
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     active, ranks = claim_schedule(k, n, batch)
     tickets = state.head + ranks
     out = _apply_dequeue(_planes(state), tickets, active, ranks,
@@ -672,7 +672,7 @@ def dist_sharded_claim_round(planes, heads, tails, batch: int, axis: str, *,
     clamp means an imbalanced mesh may claim fewer than the global budget
     this round; the remainder drains over subsequent rounds.  Returns
     ``(planes, heads, vals (batch,), ok (batch,), counts (S,))``."""
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     occs = tails - heads
     k = jnp.minimum(jnp.sum(occs), n * batch)
@@ -707,7 +707,7 @@ def dist_sharded_publish_round(planes, heads, tails, values, mask,
     they must cross the mesh to land in the replicated trace plane;
     one-collective-per-round still holds).  Returns ``(planes, tails,
     total, over, assigned (S,)[, pop_mins (S,), pop_maxs (S,)])``."""
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     mask_i = (mask > 0).astype(jnp.int32)
     meta_words = []

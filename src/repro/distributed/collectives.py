@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from . import compression
-from ..jaxcompat import axis_size as _axis_size
 
 
 # ---------------------------------------------------------------------------
@@ -32,7 +31,7 @@ def mesh_ticket_base(count: jax.Array, axis: str) -> Tuple[jax.Array, jax.Array]
     total).  One collective round hands out globally unique, ordered ticket
     blocks — the paper's leader-FAA one level up the hierarchy."""
     idx = jax.lax.axis_index(axis)
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     onehot = (jax.lax.broadcasted_iota(jnp.int32, (n,), 0) == idx)
     contrib = jnp.where(onehot, count, 0)
     sums = jax.lax.psum(contrib, axis)              # (n,) per-shard counts
@@ -49,14 +48,14 @@ def mesh_round_gather(blocks, axis: str):
     blocks into its row of an (n, ΣB_i) zero buffer and the buffer is
     psum-reduced: each row has exactly one contributor, so the reduction is
     a bit-exact integer gather, and — unlike ``all_gather``, whose output
-    the shard_map replication checker types as device-varying — the psum
-    output is *replicated-typed*.  This is what lets the distqueue round
-    state keep its ``P()`` out_spec with the checker on (no
-    ``check_rep=False``).  Returns (n, B_i)-shaped arrays, one per block.
+    shard_map's varying-manual-axes checker types as device-varying — the
+    psum output is *replicated-typed*.  This is what lets the distqueue
+    round state keep its ``P()`` out_spec with the checker on
+    (``check_vma=True``).  Returns (n, B_i)-shaped arrays, one per block.
     Per-shard counts/ticket bases fall out of the gathered masks (a cumsum),
     so one call subsumes ``mesh_ticket_base`` + payload exchange — the whole
     round costs this single collective."""
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     widths = [int(b.shape[-1]) for b in blocks]
     row = jnp.concatenate([b.astype(jnp.int32) for b in blocks])

@@ -1,21 +1,12 @@
-"""Version-compat shims over the handful of jax APIs that moved between
-the 0.4.x line (what the container ships) and the 0.5+/0.6 line (what parts
-of the codebase were written against).
+"""Thin helpers over jax APIs whose defaults do not fit this codebase.
 
-Three surfaces are papered over:
-
-* ``current_mesh()`` — the ambient mesh used for sharding hints.  New jax
-  exposes ``jax.sharding.get_abstract_mesh()``; old jax keeps the context
-  mesh on ``jax._src.mesh.thread_resources.env.physical_mesh``.  Both
-  normalize to "an object with ``.axis_names`` and a mapping ``.shape``, or
-  ``None`` when no mesh with named axes is ambient" — all call sites only
-  ever read those two attributes.
-* ``make_mesh(shape, axes)`` — ``axis_types=`` (and ``jax.sharding.AxisType``
-  itself) does not exist before 0.5; every mesh here is Auto-typed anyway,
-  which is also the old default.
-* ``cost_analysis_dict(compiled)`` — ``Compiled.cost_analysis()`` returned a
-  one-element list of dicts on old jax and returns the dict directly on new
-  jax.
+* ``current_mesh()`` — the ambient mesh (``jax.set_mesh``) used for
+  sharding hints, or ``None`` when no mesh with named axes is active; call
+  sites only read ``.axis_names`` and the ``.shape`` mapping.
+* ``make_mesh(shape, axes)`` — ``jax.make_mesh`` with Auto axis types
+  (jax defaults to Explicit, which every mesh here predates).
+* ``pvary(x, axis)`` — an idempotent ``jax.lax.pcast(..., to="varying")``:
+  the cast refuses a value that already varies over the axis.
 """
 
 from __future__ import annotations
@@ -26,63 +17,20 @@ import jax
 
 
 def current_mesh() -> Optional[object]:
-    """The ambient mesh (``with mesh:`` / ``use_mesh`` context), or ``None``
-    when no mesh with named axes is active."""
-    mesh = None
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        try:
-            mesh = get_am()
-        except Exception:
-            mesh = None
-    if mesh is None or not getattr(mesh, "axis_names", None):
-        try:  # old jax: the `with Mesh(...)` context manager's thread state
-            from jax._src import mesh as mesh_lib
-            mesh = mesh_lib.thread_resources.env.physical_mesh
-        except Exception:
-            return None
-    if mesh is None or not getattr(mesh, "axis_names", None):
-        return None
-    return mesh
+    """The ambient mesh (``jax.set_mesh`` context), or ``None`` when no
+    mesh with named axes is active."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types on every jax version."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def axis_size(axis: str):
-    """``jax.lax.axis_size`` (new jax) or a statically-evaluated psum of 1
-    over the axis (old jax — the operand is a constant, so no collective is
-    actually emitted)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis)
-    return jax.lax.psum(1, axis)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def pvary(x, axis: str):
-    """Idempotent ``jax.lax.pvary``: promote to axis-varying only if not
-    already.  A no-op on jax versions without the varying-manual-axes type
-    system (where every shard_map value is already axis-varying)."""
-    fn = getattr(jax.lax, "pvary", None)
-    if fn is None:
+    """Promote ``x`` to axis-varying unless it already is."""
+    if axis in jax.typeof(x).vma:
         return x
-    try:
-        if axis in jax.typeof(x).vma:
-            return x
-    except AttributeError:
-        pass
-    return fn(x, (axis,))
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` to a plain dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost) if cost else {}
+    return jax.lax.pcast(x, axis, to="varying")

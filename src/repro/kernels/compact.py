@@ -25,10 +25,12 @@ Two faces, bit-identical (asserted by tests):
   rank-base commit per block into an SMEM accumulator, and a masked
   drop-scatter into a full-width dense output block that persists across
   the (sequential) grid.  Blocks are up to ``BLOCK_LANES`` lanes so huge
-  child waves don't pay per-step dispatch overhead.
-* ``compact_planes`` — the pure-jnp ``lax.associative_scan`` twin for
-  shard_map / while-loop-inlined paths (the mesh engines), exactly like
-  ``ring_slots.enq_planes`` twins ``ring_enqueue``.
+  child waves don't pay per-step dispatch overhead.  v5e's Mosaic
+  lowering refuses the ``cumsum`` and the scatter, so it is the
+  interpret-mode reference only.
+* ``compact_planes`` — the pure-jnp ``lax.associative_scan`` twin that
+  every round engine inlines, exactly like ``ring_slots.enq_planes``
+  twins ``ring_enqueue``.
 
 Both return the TRUE popcount, not the clamped one: a wave whose live
 children exceed the compact width necessarily overflows its engine (the
@@ -127,7 +129,9 @@ def wave_compact(mask, planes, *, width: int, interpret=None):
     the Pallas face.  Same contract and bit-identical results as
     ``compact_planes`` (rank ≥ width drops, TRUE popcount returned);
     ``interpret=None`` resolves via REPRO_PALLAS_INTERPRET / backend.
-    Arbitrary N — the wrapper zero-pads to the block grid."""
+    Arbitrary N — the wrapper zero-pads to the block grid.  Not on the
+    engine path: v5e refuses its in-kernel ``cumsum`` and scatter; engines
+    call ``compact_planes``."""
     return _wave_compact_jit(mask, tuple(planes), width=int(width),
                              interpret=resolve_interpret(interpret))
 

@@ -24,8 +24,12 @@ work: ``heap_pop_count`` pops a traced-count prefix of a fixed-width
 batch, ``heap_insert_masked`` installs a masked subset — the claim and
 publish waves of the priority mesh rounds (DESIGN.md § 6).
 
-VMEM budget: 2 planes × 2^cap_log2 × 4 B plus the batch — a 64Ki-node
-heap costs 512 KiB, comfortably inside the 16 MiB/core budget.
+VMEM: the Pallas kernel holds 2 planes × 2^cap_log2 × 4 B plus the batch
+in VMEM, in and out, and indexes them with dynamic scalar loads and
+stores, which v5e's Mosaic lowering refuses ("Cannot store scalars to
+VMEM").  The round engines therefore run ``heap_planes`` on every
+backend; ``heap_apply`` stays as the differential reference of the
+interpret-mode tests.
 """
 
 from __future__ import annotations
@@ -135,7 +139,8 @@ def heap_apply(keys, vals, size, ops, opkeys, opvals, *, cap_log2: int,
     ``interpret=None`` resolves via REPRO_PALLAS_INTERPRET / backend.
     Returns ``(keys, vals, new_size, out_keys, out_vals, ok)`` where
     ``out_*[i]`` carry delete-min results and ``ok[i]`` certifies op i
-    applied."""
+    applied.  Not on the engine path: v5e refuses its dynamic scalar VMEM
+    stores; engines call ``heap_planes``."""
     return _heap_apply_jit(keys, vals, size, ops, opkeys, opvals,
                            cap_log2=cap_log2, arity_log2=arity_log2,
                            interpret=resolve_interpret(interpret))

@@ -16,8 +16,12 @@ plane updates are exposed as pure-jnp functions (``enq_planes`` /
 ``deq_planes``) so the fused round engine can inline them into a jitted
 ``while_loop`` without a host round-trip.
 
-VMEM budget: the whole ring (4 × 2n × 4 B) plus the op batch live in VMEM;
-for n ≤ 64Ki that is ≤ 2 MiB — comfortably inside the 16 MiB/core budget.
+VMEM: the Pallas kernels hold the whole ring (4 × 2n × 4 B) plus the op
+batch in VMEM, in and out — 16 MiB of planes already at n = 256Ki, past
+the 16 MiB scoped budget, and 512 MiB at the 2^23-entry ring a chip run
+holds.  The round engines therefore run ``enq_planes``/``deq_planes``,
+whose planes XLA keeps in HBM; the kernels stay as the differential
+reference of the interpret-mode tests.
 """
 
 from __future__ import annotations
@@ -231,7 +235,9 @@ def ring_enqueue(cycles, safes, enqs, idxs, tickets, values, head, *,
     """Apply a wave of TRYENQ installs (one masked scatter).  All field
     arrays are (2n,) int32; tickets/values are (B,) int32 (ticket -1 =
     inactive).  ``interpret=None`` resolves via REPRO_PALLAS_INTERPRET /
-    backend.  Returns (cycles, safes, enqs, idxs, ok)."""
+    backend.  Returns (cycles, safes, enqs, idxs, ok).  Not on the engine
+    path: v5e's Mosaic lowering refuses its whole-ring gather ("Only 2D
+    gather is supported"); engines call ``enq_planes``."""
     return _ring_enqueue_jit(cycles, safes, enqs, idxs, tickets, values,
                              head, nslots_log2=nslots_log2, idx_bot=idx_bot,
                              interpret=resolve_interpret(interpret))
@@ -267,7 +273,9 @@ def _ring_dequeue_jit(cycles, safes, enqs, idxs, tickets, *,
 def ring_dequeue(cycles, safes, enqs, idxs, tickets, *,
                  nslots_log2: int, idx_bot: int, interpret=None):
     """Apply a wave of TRYDEQ consumes (one masked scatter).  Returns
-    (cycles, safes, enqs, idxs, values, ok)."""
+    (cycles, safes, enqs, idxs, values, ok).  Not on the engine path:
+    v5e's Mosaic lowering refuses its whole-ring gather; engines call
+    ``deq_planes``."""
     return _ring_dequeue_jit(cycles, safes, enqs, idxs, tickets,
                              nslots_log2=nslots_log2, idx_bot=idx_bot,
                              interpret=resolve_interpret(interpret))

@@ -9,7 +9,10 @@ accumulator that carries across the (sequential) TPU grid — the same
 aggregation hierarchy, one level up.
 
 Block shape: (8, 128) int32 lanes per grid step — one VREG tile.  The mask
-is reshaped (N,) → (N/1024, 8, 128) by the wrapper.
+is reshaped (N,) → (N/1024, 8, 128) by the wrapper.  The in-block prefix
+rank is two triangular f32 matmuls rather than ``cumsum``, which the v5e
+Mosaic lowering does not implement; integer counts are exact in f32 far
+past the 1024 lanes of a block.
 """
 
 from __future__ import annotations
@@ -26,6 +29,25 @@ from .pallas_env import resolve_interpret
 LANES = 8 * 128  # one (8, 128) VREG tile per grid step
 
 
+def exclusive_rank(a: jax.Array) -> jax.Array:
+    """Row-major exclusive prefix sum of an (R, 128) int32 tile: the in-row
+    prefix is a strictly-upper-triangular matmul, each row's offset the sum
+    of the totals of the rows above it.  Exact while the tile sums below
+    2^24 (f32 mantissa, HIGHEST precision)."""
+    rows, cols = a.shape
+    af = a.astype(jnp.float32)
+    k = jax.lax.broadcasted_iota(jnp.int32, (cols, cols), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (cols, cols), 1)
+    rank = jnp.dot(af, (k < j).astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+    totals = jnp.sum(af, axis=1, keepdims=True)     # (R, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    for q in range(rows - 1):
+        rank = rank + jnp.where(row > q, totals[q:q + 1, :], 0.0)
+    return rank.astype(jnp.int32)
+
+
 def _wavefaa_kernel(counter_ref, active_ref, tickets_ref, newctr_ref, acc_ref):
     step = pl.program_id(0)
 
@@ -34,11 +56,8 @@ def _wavefaa_kernel(counter_ref, active_ref, tickets_ref, newctr_ref, acc_ref):
         acc_ref[0] = counter_ref[0]
 
     a = active_ref[...].astype(jnp.int32)           # (8, 128) block
-    flat = a.reshape(1, LANES)
-    rank = jnp.cumsum(flat, axis=1) - flat          # exclusive prefix rank
     base = acc_ref[0]
-    t = jnp.where(flat > 0, base + rank, -1)
-    tickets_ref[...] = t.reshape(a.shape)
+    tickets_ref[...] = jnp.where(a > 0, base + exclusive_rank(a), -1)
     # ONE commit per block — the leader FAA of Alg. 1
     acc_ref[0] = base + jnp.sum(a)
 

@@ -29,7 +29,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from ..configs import SHAPES, get_config, list_archs          # noqa: E402
-from ..jaxcompat import cost_analysis_dict                     # noqa: E402
 from ..models import param_specs                               # noqa: E402
 from . import steps as S                                       # noqa: E402
 from .hlo_analysis import analyze_hlo_text                     # noqa: E402
@@ -124,7 +123,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, results: dict,
     try:
         compiled, lowered = lower_cell(arch, shape, mesh)
         mem = compiled.memory_analysis()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         hlo = analyze_hlo_text(compiled.as_text())
         rec = {
             "status": "ok",

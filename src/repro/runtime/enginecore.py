@@ -50,15 +50,11 @@ from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..kernels.ring_slots import SPAN_ROUND_CAP
 from ..obs.spans import Spans, span_init
 from ..obs.trace import SyncPoint, Telemetry, trace_init, trace_record
-
-try:  # jax>=0.4.35 moved PartitionSpec construction; keep one import site
-    from jax.sharding import PartitionSpec as P
-except ImportError:  # pragma: no cover
-    from jax.experimental import PartitionSpec as P
 
 
 def _sds(shape, dtype=jnp.int32):
@@ -182,6 +178,9 @@ class EngineCore:
     # shards: the relaxed round's publish psum is a collective, and a
     # shard exiting early would deadlock the others.
     _extra_cond = None
+    # mesh engines: the shard_map in_specs of the megaround, from which the
+    # chunk loop places the initial carry on the mesh (None = chip engine)
+    _carry_specs = None
 
     def _reset(self) -> None:
         self.stats: Dict[str, int] = {}
@@ -350,6 +349,17 @@ class EngineCore:
         ONE host-sync readback per chunk."""
         self._tel_plane = lambda: ext[0]
         self._span_plane = lambda: ext[1]
+        specs = self._carry_specs
+        if specs is not None:
+            # mesh engines: the host-built seed planes and acc are placed
+            # with the mesh shardings once; every later chunk takes the
+            # megaround's own (already sharded) outputs
+            shard = jax.tree_util.tree_map(
+                lambda s: NamedSharding(self.mesh, s),
+                tuple(specs[:5]) + tuple(specs[6:]),
+                is_leaf=lambda s: isinstance(s, P))
+            placed = jax.device_put(tuple(state) + tuple(ext), shard)
+            state[:], ext[:] = placed[:5], placed[5:]
 
         def chunk_fn(limit):
             out = self._megaround(*state, jnp.int32(limit), *ext)

@@ -9,13 +9,19 @@ ticket → enqueue cycle inside ONE jitted ``lax.while_loop``
 (``enginecore.fused_loop``):
 
 * head/tail (ring) and size (heap) are device scalars in the loop carry;
-* the dequeue wave is the vectorized ``ring_dequeue`` scatter kernel;
+* the dequeue wave is the vectorized ``deq_planes`` gather/scatter;
 * child tickets come from the ``wavefaa`` kernel over the spawn mask — the
   in-loop leader-FAA of paper Alg. 1 — instead of host ticket math;
-* the enqueue wave installs ALL children in one vectorized scatter (the
-  legacy path chunks them into ``batch``-sized dispatches);
+* the enqueue wave installs ALL children in one vectorized ``enq_planes``
+  scatter (the legacy path chunks them into ``batch``-sized dispatches);
 * the host syncs only at quiescence, or every ``sync_every`` rounds when
   the caller wants a stats heartbeat.
+
+Kernel faces: ``wavefaa`` is the one Pallas kernel in a round.  The ring,
+heap and compaction waves run as their pure-jnp plane functions
+(``enq_planes``/``deq_planes``, ``heap_planes``, ``compact_planes``) on
+every backend — XLA places the planes in HBM, where the Pallas twins kept
+the whole ring or heap in VMEM and the v5e compiler refuses them.
 
 Overflow and ``max_rounds`` truncation cannot raise from traced code, so
 the loop carries an overflow flag, exits early, and the host driver raises
@@ -41,18 +47,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.compact import compact_width, wave_compact
+from ..kernels.compact import compact_planes, compact_width
 from ..kernels.heap_batch import (KEY_INF as HEAP_KEY_INF, OP_DELMIN,
-                                  OP_INSERT, OP_NOP, heap_apply, heap_planes)
+                                  OP_INSERT, OP_NOP, heap_planes)
 from ..kernels.pallas_env import resolve_interpret
-from ..kernels.ring_slots import (deq_planes, enq_planes, ring_dequeue,
-                                  ring_enqueue)
+from ..kernels.ring_slots import deq_planes, enq_planes
 from ..kernels.wavefaa import LANES, wavefaa
 from ..obs.spans import Spans, span_record, span_tick
 from ..obs.trace import Telemetry, masked_min_max
 from .enginecore import EngineCore, _sds, deprecated_engine
 
 IDX_BOT = 2 ** 31 - 1           # ⊥ (⊥_c = IDX_BOT - 1); payloads must be smaller
+
+# host-callable plane waves (seeding, the legacy per-round loop)
+enq_wave = jax.jit(enq_planes, static_argnames=("nslots_log2", "idx_bot"))
+deq_wave = jax.jit(deq_planes, static_argnames=("nslots_log2", "idx_bot"))
 
 
 class RingState(NamedTuple):
@@ -165,22 +174,15 @@ class RingEngine(EngineCore):
         cyc, saf, enq, idx, head, tail = st
         k = jnp.minimum(jnp.int32(batch), tail - head)
         dtickets = jnp.where(lane < k, head + lane, -1)
-        if sps:
-            # span path inlines the pure-jnp twin of the dequeue kernel
-            # in packed-flag mode: the birth stamp lives in the high
-            # bits of the enq-flag plane, so it rides the flag
-            # gather/scatter the round already pays for — zero extra
-            # ops, zero extra carry (every scatter here copies its
-            # whole plane per round, so a separate stamp plane costs
-            # real microseconds; measured in DESIGN.md § 7.6)
-            cyc, saf, enq, idx, vals, okw, bout = deq_planes(
-                cyc, saf, enq, idx, dtickets, nslots_log2=nslots_log2,
-                idx_bot=IDX_BOT, birth_packed=True)
-            ok = okw.astype(bool)
-        else:
-            cyc, saf, enq, idx, vals, ok = ring_dequeue(
-                cyc, saf, enq, idx, dtickets, nslots_log2=nslots_log2,
-                idx_bot=IDX_BOT, interpret=interp)
+        # with spans the dequeue runs in packed-flag mode: the birth stamp
+        # lives in the high bits of the enq-flag plane, so it rides the
+        # flag gather/scatter the round already pays for — zero extra
+        # ops, zero extra carry (measured in DESIGN.md § 7.6)
+        deq = deq_planes(cyc, saf, enq, idx, dtickets,
+                         nslots_log2=nslots_log2, idx_bot=IDX_BOT,
+                         birth_packed=sps)
+        cyc, saf, enq, idx, vals = deq[:5]
+        ok = deq[5].astype(bool)
         head = head + k
         acc, cvals, cmask = self.step_fn(acc, vals, ok)
         cm = jnp.broadcast_to(cmask.astype(bool), cvals.shape).reshape(-1)
@@ -203,21 +205,16 @@ class RingEngine(EngineCore):
             # children in wavefaa rank order, so tickets are the
             # contiguous run tail + [0, n_child) — bit-identical
             # (ticket, value) scatters to the sparse install
-            (cv,), n_child = wave_compact(cm.astype(jnp.int32), (cv,),
-                                          width=wdth, interpret=interp)
+            (cv,), n_child = compact_planes(cm.astype(jnp.int32), (cv,),
+                                            width=wdth)
             over = (tail + n_child - head) > capacity
             lane_w = jnp.arange(wdth, dtype=jnp.int32)
             etickets = jnp.where((lane_w < n_child) & ~over,
                                  tail + lane_w, -1)
-        if sps:
-            cyc, saf, enq, idx, _ = enq_planes(
-                cyc, saf, enq, idx, etickets, cv, head,
-                nslots_log2=nslots_log2, idx_bot=IDX_BOT,
-                birth_round=sp.round)
-        else:
-            cyc, saf, enq, idx, _ = ring_enqueue(
-                cyc, saf, enq, idx, etickets, cv, head,
-                nslots_log2=nslots_log2, idx_bot=IDX_BOT, interpret=interp)
+        cyc, saf, enq, idx, _ = enq_planes(
+            cyc, saf, enq, idx, etickets, cv, head,
+            nslots_log2=nslots_log2, idx_bot=IDX_BOT,
+            birth_round=sp.round if sps else None)
         tail = jnp.where(over, tail, tail + n_child)
         total = jnp.where(over, 0, n_child)
         telinfo = None
@@ -226,7 +223,7 @@ class RingEngine(EngineCore):
             telinfo = (k, total, tail - head, mn, mx)
         if sps:
             cls = self._span_cls(vals, jnp.zeros_like(vals))
-            sp = span_record(sp, cls, sp.round - bout, ok, vals)
+            sp = span_record(sp, cls, sp.round - deq[6], ok, vals)
             sp = span_tick(sp)
         return (RingState(cyc, saf, enq, idx, head, tail), acc, k, total,
                 over, telinfo, sp, births)
@@ -241,11 +238,10 @@ class RingEngine(EngineCore):
             return st
         tickets = jnp.asarray(st.tail + np.arange(n, dtype=np.int64),
                               jnp.int32)
-        cyc, saf, enq, idx, ok = ring_enqueue(
+        cyc, saf, enq, idx, ok = enq_wave(
             st.cycles, st.safes, st.enqs, st.idxs, tickets,
             jnp.asarray(initial), jnp.asarray(st.head, jnp.int32),
-            nslots_log2=self.nslots_log2, idx_bot=IDX_BOT,
-            interpret=self.interpret)
+            nslots_log2=self.nslots_log2, idx_bot=IDX_BOT)
         assert bool(ok.all()), "exact tickets cannot miss"
         return RingState(cyc, saf, enq, idx, st.head, st.tail + n)
 
@@ -285,15 +281,14 @@ class RingEngine(EngineCore):
 
 
 class HeapEngine(EngineCore):
-    """``RingEngine``'s priority configuration: chains ``heap_apply`` pop
+    """``RingEngine``'s priority configuration: chains ``heap_planes`` pop
     and insert batches under the core's fused loop with the heap size as a
     device scalar.  The pad/op vectors are loop-invariant constants (hoisted
     by XLA), and children insert as one masked batch in row-major order —
     identical heap evolution to the legacy chunked inserts."""
 
     def __init__(self, step_fn: PriorityStepFn, *, capacity_log2: int = 10,
-                 batch: int = 64, arity_log2: int = 2, interpret=None,
-                 sync_every: int = 0,
+                 batch: int = 64, arity_log2: int = 2, sync_every: int = 0,
                  telemetry: Optional[Telemetry] = None,
                  spans: Optional[Spans] = None, compact=None) -> None:
         self.step_fn = jax.jit(step_fn)
@@ -304,7 +299,6 @@ class HeapEngine(EngineCore):
             raise ValueError(f"batch {batch} exceeds heap capacity "
                              f"{self.capacity}")
         self.arity_log2 = arity_log2
-        self.interpret = resolve_interpret(interpret)
         self.sync_every = sync_every
         self.telemetry = telemetry
         self.spans = spans
@@ -323,25 +317,17 @@ class HeapEngine(EngineCore):
     def _round(self, st, acc, tel=False, sp=None, births=None):
         batch, capacity = self.batch, self.capacity
         cap_log2, arity_log2 = self.capacity_log2, self.arity_log2
-        interp = self.interpret
         sps = sp is not None
         lane = jnp.arange(batch, dtype=jnp.int32)
         pad = jnp.full((batch,), HEAP_KEY_INF, jnp.int32)
         keys, vals, size = st
         k = jnp.minimum(jnp.int32(batch), size)
         pop_ops = jnp.where(lane < k, OP_DELMIN, OP_NOP)
-        if sps:
-            # span path inlines the rider-capable pure-jnp heap twin
-            # (bit-identical heap evolution to the kernel; the rider
-            # plane carries the birth stamps through every sift)
-            (keys, vals, size, outk, outv, ok, births,
-             bout) = heap_planes(
-                keys, vals, size, pop_ops, pad, pad, cap_log2=cap_log2,
-                arity_log2=arity_log2, rider=births)
-        else:
-            keys, vals, size, outk, outv, ok = heap_apply(
-                keys, vals, size, pop_ops, pad, pad, cap_log2=cap_log2,
-                arity_log2=arity_log2, interpret=interp)
+        # with spans the births plane rides every sift as the rider plane
+        pop = heap_planes(keys, vals, size, pop_ops, pad, pad,
+                          cap_log2=cap_log2, arity_log2=arity_log2,
+                          rider=births)
+        keys, vals, size, outk, outv, ok = pop[:6]
         acc, ckeys, cvals, cmask = self.step_fn(acc, outk, outv, ok)
         cm = jnp.broadcast_to(cmask.astype(bool), ckeys.shape).reshape(-1)
         ckf = ckeys.reshape(-1).astype(jnp.int32)
@@ -356,29 +342,26 @@ class HeapEngine(EngineCore):
             over = size + n_child > capacity
             ins_ops = jnp.where(cm & ~over, OP_INSERT, OP_NOP)
         else:
-            (ckf, cvf), n_child = wave_compact(
-                cm.astype(jnp.int32), (ckf, cvf), width=wdth,
-                interpret=interp)
+            (ckf, cvf), n_child = compact_planes(
+                cm.astype(jnp.int32), (ckf, cvf), width=wdth)
             over = size + n_child > capacity
             lane_w = jnp.arange(wdth, dtype=jnp.int32)
             ins_ops = jnp.where((lane_w < n_child) & ~over,
                                 OP_INSERT, OP_NOP)
-        if sps:
-            keys, vals, size, _, _, _, births, _ = heap_planes(
-                keys, vals, size, ins_ops, ckf, cvf, cap_log2=cap_log2,
-                arity_log2=arity_log2, rider=births, oprider=sp.round)
-        else:
-            keys, vals, size, _, _, _ = heap_apply(
-                keys, vals, size, ins_ops, ckf, cvf, cap_log2=cap_log2,
-                arity_log2=arity_log2, interpret=interp)
+        ins = heap_planes(keys, vals, size, ins_ops, ckf, cvf,
+                          cap_log2=cap_log2, arity_log2=arity_log2,
+                          rider=pop[6] if sps else None,
+                          oprider=sp.round if sps else None)
+        keys, vals, size = ins[:3]
         total = jnp.where(over, 0, n_child)
         telinfo = None
         if tel:
             mn, mx = masked_min_max(outk, ok)      # popped-key extrema
             telinfo = (k, total, size, mn, mx)
         if sps:
+            births = ins[6]
             cls = self._span_cls(outk, jnp.zeros_like(outk))
-            sp = span_record(sp, cls, sp.round - bout, ok, outv)
+            sp = span_record(sp, cls, sp.round - pop[7], ok, outv)
             sp = span_tick(sp)
         return (HeapState(keys, vals, size), acc, k, total, over, telinfo,
                 sp, births)
@@ -393,10 +376,10 @@ class HeapEngine(EngineCore):
         if n == 0:
             return st
         ops = jnp.full((n,), OP_INSERT, jnp.int32)
-        keys, vals, size, _, _, ok = heap_apply(
+        keys, vals, size, _, _, ok = heap_planes(
             st.keys, st.vals, jnp.asarray(st.size, jnp.int32), ops,
             jnp.asarray(ik), jnp.asarray(iv), cap_log2=self.capacity_log2,
-            arity_log2=self.arity_log2, interpret=self.interpret)
+            arity_log2=self.arity_log2)
         assert bool(ok.all()), "capacity was checked: inserts cannot miss"
         return HeapState(keys, vals, int(size))
 

@@ -50,11 +50,10 @@ against the replicated baseline on totals and order-insensitive
 accumulators (claim *order* legitimately differs — the schedule is
 load-aware, not rank-sliced).
 
-Note on the replication checker: the per-round distqueue API passes
-``check_rep=True``, but ``lax.while_loop`` has no replication rule in
-this jax line, so every megaround shard_map is built with
-``check_rep=False``.  Per-shard state bit-identity is asserted by tests
-instead.
+Note on the varying-manual-axes checker: the per-round distqueue API
+is checked with ``check_vma=True``; the engine shard_maps are built with
+``check_vma=False``, and the replicated typing of their loop carry is
+asserted by tests (per-shard state bit-identity) instead.
 
 Overflow and truncation follow the core contract: a flag in the carry
 exits the loop and the host driver raises ``RuntimeError`` at the next
@@ -73,7 +72,6 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.distqueue import (DistHeapState, DistQueueState,
@@ -189,10 +187,11 @@ class MeshRingEngine(_MeshFifoBase):
                     P(), P(), P(), P()) + obs
         out_specs = (reg.spec("ring"), P(self.axis),
                      P(), P(), P(), P(), P()) + obs
-        self._megaround = jax.jit(shard_map(
+        self._carry_specs = in_specs
+        self._megaround = jax.jit(jax.shard_map(
             self._megaround_impl, mesh=self.mesh,
             in_specs=in_specs, out_specs=out_specs,
-            check_rep=False))   # while_loop has no replication rule
+            check_vma=False))   # see the module note
 
     # -- seeding (host-side, before shard_map: planes are plain jnp) --------
     def _seed(self, state: DistQueueState,
@@ -362,10 +361,11 @@ class ShardedMeshRingEngine(_MeshFifoBase):
         obs = (reg.spec("trace"), reg.spec("span"), reg.spec("births"))
         in_specs = (qspec, P(self.axis), P(), P(), P(), P()) + obs
         out_specs = (qspec, P(self.axis), P(), P(), P(), P(), P()) + obs
-        self._megaround = jax.jit(shard_map(
+        self._carry_specs = in_specs
+        self._megaround = jax.jit(jax.shard_map(
             self._megaround_impl, mesh=self.mesh,
             in_specs=in_specs, out_specs=out_specs,
-            check_rep=False))   # while_loop has no replication rule
+            check_vma=False))   # see the module note
 
     # -- seeding: round-robin spray by seed rank into the local rings -------
     def _seed(self, state: DistShardedQueueState,
@@ -515,11 +515,11 @@ class MeshRoundRunner(_MeshFifoBase):
         else:
             self._engine = None
             # legacy: acc rides stacked (shards, ...) through P(axis)
-            self._round_jit = jax.jit(shard_map(
+            self._round_jit = jax.jit(jax.shard_map(
                 self._legacy_round, mesh=self.mesh,
                 in_specs=(P(), P(self.axis)),
                 out_specs=(P(), P(self.axis), P(), P(), P()),
-                check_rep=False))   # acc diverges per shard (P(axis) io)
+                check_vma=False))   # acc diverges per shard (P(axis) io)
 
     # reuse the replicated engine's round/seed for the legacy baseline
     _seed = MeshRingEngine._seed
@@ -957,10 +957,11 @@ class MeshHeapEngine(_PriorityMeshBase):
         obs = (reg.spec("trace"), reg.spec("span"), reg.spec("births"))
         in_specs = (qspec, P(self.axis), P(), P(), P(), P()) + obs
         out_specs = (qspec, P(self.axis), P(), P(), P(), P(), P()) + obs
-        self._megaround = jax.jit(shard_map(
+        self._carry_specs = in_specs
+        self._megaround = jax.jit(jax.shard_map(
             self._megaround_impl, mesh=self.mesh,
             in_specs=in_specs, out_specs=out_specs,
-            check_rep=False))   # while_loop has no replication rule
+            check_vma=False))   # see the module note
 
     def _megaround_impl(self, qstate, acc, processed, spawned, max_occ,
                         limit, tp=None, sp=None, births=None):
@@ -1082,9 +1083,9 @@ class PriorityMeshRoundRunner(_PriorityMeshBase):
         # the fused engine never pays
         out_specs = out_core + ((sp, sp, sp, P(), P(), P())
                                 if trace else ())
-        self._round_jit = jax.jit(shard_map(
+        self._round_jit = jax.jit(jax.shard_map(
             self._legacy_round, mesh=self.mesh, in_specs=in_specs,
-            out_specs=out_specs, check_rep=False))
+            out_specs=out_specs, check_vma=False))
 
     def _legacy_round(self, qstate, births, acc):
         qstate, births = self._unstack_round_io(qstate, births)

@@ -1,13 +1,13 @@
-"""Round-based deterministic task loop on the Pallas ring (DESIGN.md § 4.3).
+"""Round-based deterministic task loop on the device ring (DESIGN.md § 4.3).
 
 The sim face (`executor.py`) explores adversarial interleavings; this face is
 the *device* execution model: task scheduling advances in jitted rounds, and
 within a round every queue operation is ordered by ticket — the batched
 analogue of Lemma III.1, with no nondeterminism left.  One round is
 
-    dequeue a batch of task values from the ring (``ring_dequeue``),
+    dequeue a batch of task values from the ring (``deq_planes``),
     run the user's jitted step function on the batch,
-    enqueue the children it emits (``ring_enqueue``) in row-major order.
+    enqueue the children it emits (``enq_planes``) in row-major order.
 
 Two execution engines share this contract:
 
@@ -16,9 +16,10 @@ Two execution engines share this contract:
   device scalars and ``wavefaa`` as the in-loop child-ticket source; the
   host syncs only at quiescence (or every ``sync_every`` rounds).
 * **legacy** (``fused=False``) — one host-driven round per iteration:
-  head/tail as host ints, exact ``np.arange`` tickets, one kernel dispatch
-  per op wave.  Slower (every round is a host sync) but each round is a
-  separate, inspectable step — keep it for adversarial/step-debug use.
+  head/tail as host ints, exact ``np.arange`` tickets, one jitted
+  dispatch per op wave.  Slower (every round is a host sync) but each
+  round is a separate, inspectable step — keep it for
+  adversarial/step-debug use.
 
 Both engines are bit-identical (same acc, same planes, same head/tail —
 asserted by tests) and raise ``RuntimeError`` on ring/heap overflow and on
@@ -43,13 +44,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.distqueue import dist_dequeue_round, dist_enqueue_round
-from ..kernels.heap_batch import KEY_INF as HEAP_KEY_INF, heap_apply
-from ..kernels.pallas_env import resolve_interpret
-from ..kernels.ring_slots import ring_dequeue, ring_enqueue
+from ..kernels.heap_batch import KEY_INF as HEAP_KEY_INF, heap_planes
 from .enginecore import register_engine
 from .fusedrounds import (IDX_BOT, HeapEngine, HeapState, PriorityStepFn,
-                          RingEngine, RingState, StepFn, heap_init,
-                          ring_init)
+                          RingEngine, RingState, StepFn, deq_wave, enq_wave,
+                          heap_init, ring_init)
 
 __all__ = [
     "IDX_BOT", "HeapState", "PriorityRoundRunner", "PriorityStepFn",
@@ -59,7 +58,7 @@ __all__ = [
 
 
 class RoundRunner:
-    """Drives ``step_fn`` to quiescence through the Pallas ring.
+    """Drives ``step_fn`` to quiescence through the device ring.
 
     ``fused=True`` (default) delegates to the device-resident megaround
     loop; ``fused=False`` keeps the legacy host-driven round loop.  Both
@@ -75,7 +74,6 @@ class RoundRunner:
         self.nslots_log2 = capacity_log2 + 1
         self.capacity = 1 << capacity_log2
         self.batch = batch
-        self.interpret = resolve_interpret(interpret)
         self.fused = fused
         self.telemetry = telemetry
         self.spans = spans
@@ -90,7 +88,7 @@ class RoundRunner:
         if fused:
             self._engine = RingEngine(
                 step_fn, capacity_log2=capacity_log2, batch=batch,
-                interpret=self.interpret, sync_every=sync_every,
+                interpret=interpret, sync_every=sync_every,
                 telemetry=telemetry, spans=spans, compact=compact)
         else:
             self._engine = None
@@ -112,12 +110,11 @@ class RoundRunner:
         self._enq_t[:k] = st.tail + np.arange(k, dtype=np.int32)
         self._enq_v.fill(-1)
         self._enq_v[:k] = vals
-        cyc, saf, enq, idx, ok = ring_enqueue(
+        cyc, saf, enq, idx, ok = enq_wave(
             st.cycles, st.safes, st.enqs, st.idxs,
             jnp.asarray(self._enq_t), jnp.asarray(self._enq_v),
             jnp.asarray(st.head, jnp.int32),
-            nslots_log2=self.nslots_log2, idx_bot=IDX_BOT,
-            interpret=self.interpret)
+            nslots_log2=self.nslots_log2, idx_bot=IDX_BOT)
         self._host_syncs += 1
         assert bool(ok[:k].all()), "exact tickets cannot miss"
         return RingState(cyc, saf, enq, idx, st.head, st.tail + k)
@@ -146,11 +143,11 @@ class RoundRunner:
             k = min(self.batch, st.occupancy)
             self._deq_t.fill(-1)
             self._deq_t[:k] = st.head + np.arange(k, dtype=np.int32)
-            cyc, saf, enq, idx, vals, ok = ring_dequeue(
+            cyc, saf, enq, idx, vals, ok = deq_wave(
                 st.cycles, st.safes, st.enqs, st.idxs,
                 jnp.asarray(self._deq_t),
-                nslots_log2=self.nslots_log2, idx_bot=IDX_BOT,
-                interpret=self.interpret)
+                nslots_log2=self.nslots_log2, idx_bot=IDX_BOT)
+            ok = ok.astype(bool)
             self._host_syncs += 1
             assert bool(ok[:k].all()), "exact tickets cannot miss"
             st = RingState(cyc, saf, enq, idx, st.head + k, st.tail)
@@ -179,29 +176,28 @@ class RoundRunner:
 
 
 # ---------------------------------------------------------------------------
-# Priority rounds on the Pallas heap (DESIGN.md § 5.6)
+# Priority rounds on the device heap (DESIGN.md § 5.6)
 # ---------------------------------------------------------------------------
 
 
 class PriorityRoundRunner:
     """``RoundRunner``'s priority twin: drives ``step_fn`` to quiescence
-    through the Pallas heap kernel.  One round pops the ``batch`` smallest
-    (key, val) pairs (EDF: earliest deadlines), runs the jitted step, and
-    inserts the children it emits in row-major order — every kernel batch
-    is applied in batch-index order, so the whole run is bit-deterministic
-    exactly like the FIFO rounds.  ``fused=True`` (default) chains the
+    through the batched device heap (``heap_planes``).  One round pops
+    the ``batch`` smallest (key, val) pairs (EDF: earliest deadlines),
+    runs the jitted step, and inserts the children it emits in row-major
+    order — every heap batch is applied in batch-index order, so the whole
+    run is bit-deterministic exactly like the FIFO rounds.  ``fused=True`` (default) chains the
     pop/insert batches under one device-resident ``lax.while_loop``."""
 
     def __init__(self, step_fn: PriorityStepFn, *, capacity_log2: int = 10,
-                 batch: int = 64, arity_log2: int = 2, interpret=None,
-                 fused: bool = True, sync_every: int = 0,
-                 telemetry=None, spans=None, compact=None) -> None:
+                 batch: int = 64, arity_log2: int = 2, fused: bool = True,
+                 sync_every: int = 0, telemetry=None, spans=None,
+                 compact=None) -> None:
         self.step_fn = jax.jit(step_fn)
         self.capacity_log2 = capacity_log2
         self.capacity = 1 << capacity_log2
         self.batch = batch
         self.arity_log2 = arity_log2
-        self.interpret = resolve_interpret(interpret)
         self.fused = fused
         self.telemetry = telemetry
         self.spans = spans
@@ -216,9 +212,8 @@ class PriorityRoundRunner:
         if fused:
             self._engine = HeapEngine(
                 step_fn, capacity_log2=capacity_log2, batch=batch,
-                arity_log2=arity_log2, interpret=self.interpret,
-                sync_every=sync_every, telemetry=telemetry, spans=spans,
-                compact=compact)
+                arity_log2=arity_log2, sync_every=sync_every,
+                telemetry=telemetry, spans=spans, compact=compact)
         else:
             self._engine = None
             # legacy-path op buffers, reused across rounds (safe because
@@ -230,11 +225,10 @@ class PriorityRoundRunner:
             self._pad = jnp.full((batch,), HEAP_KEY_INF, jnp.int32)
 
     def _apply(self, st: HeapState, ops, keys, vals):
-        k, v, size, outk, outv, ok = heap_apply(
+        k, v, size, outk, outv, ok = heap_planes(
             st.keys, st.vals, jnp.asarray(st.size, jnp.int32),
             ops, keys, vals,
-            cap_log2=self.capacity_log2, arity_log2=self.arity_log2,
-            interpret=self.interpret)
+            cap_log2=self.capacity_log2, arity_log2=self.arity_log2)
         self._host_syncs += 1
         return HeapState(k, v, int(size)), outk, outv, ok
 

@@ -145,7 +145,7 @@ def test_chip_fifo_compact_bit_identical():
 
 def test_chip_priority_compact_bit_identical():
     off, on = _runs(lambda c: PriorityRoundRunner(
-        _pri_step(), capacity_log2=8, batch=16, interpret=True, compact=c),
+        _pri_step(), capacity_log2=8, batch=16, compact=c),
         priority=True)
     np.testing.assert_array_equal(off[0], on[0])
     assert off[1] == on[1]

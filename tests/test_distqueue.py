@@ -1,6 +1,6 @@
 """Distributed mesh-level queue: exactly-once + FIFO under shard_map,
 with the replication checker ON (the psum-gathered rounds keep the ring
-planes replicated-typed, so no ``check_rep=False`` escape hatch), for both
+planes replicated-typed, so no ``check_vma=False`` escape hatch), for both
 application engines (vectorized ``planes`` sub-waves and the legacy serial
 ``scan``), at wrap boundaries (tickets crossing the int32 sign and the
 full 2^32 cycle boundary), with over-capacity rounds (sub-wave splitting)
@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.distqueue import (dist_claim_round, dist_dequeue_round,
@@ -32,7 +31,7 @@ ENGINES = ("planes", "scan")
 WRAP_STARTS = (None, 2 ** 30, 2 ** 31 - 64, 2 ** 32 - 64)
 
 
-def _round_fn(engine, b, check_rep=True):
+def _round_fn(engine, b, check_vma=True):
     mesh = make_mesh((1,), ("data",))
 
     def inner(state, values, emask, want):
@@ -42,10 +41,9 @@ def _round_fn(engine, b, check_rep=True):
                                              engine=engine)
         return state, granted, vals, ok
 
-    return jax.jit(shard_map(inner, mesh=mesh,
-                             in_specs=(P(), P("data"), P("data"), P("data")),
-                             out_specs=(P(), P("data"), P("data"), P("data")),
-                             check_rep=check_rep))
+    io = (P(), P("data"), P("data"), P("data"))
+    return jax.jit(jax.shard_map(inner, mesh=mesh, in_specs=io,
+                                 out_specs=io, check_vma=check_vma))
 
 
 def test_single_device_semantics():
@@ -180,9 +178,9 @@ def test_claim_round_balanced_schedule():
         state, vals, ok = dist_claim_round(state, k[0], 8, "data")
         return state, granted, vals, ok
 
-    f = jax.jit(shard_map(inner, mesh=mesh,
-                          in_specs=(P(), P("data"), P("data"), P()),
-                          out_specs=(P(), P("data"), P("data"), P("data"))))
+    f = jax.jit(jax.shard_map(
+        inner, mesh=mesh, in_specs=(P(), P("data"), P("data"), P()),
+        out_specs=(P(), P("data"), P("data"), P("data"))))
     state = dist_queue_init(16)
     vals = jnp.arange(1, 9, dtype=jnp.int32)
     ones = jnp.ones(8, jnp.int32)
@@ -200,7 +198,6 @@ _SUBPROC = textwrap.dedent("""
     import sys; sys.path.insert(0, {src!r})
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.core.distqueue import (dist_queue_init, dist_enqueue_round,
                                       dist_dequeue_round)
     from repro.jaxcompat import make_mesh
@@ -216,8 +213,8 @@ _SUBPROC = textwrap.dedent("""
                                                  engine=engine)
             return state, granted, vals, ok
         # replication checker ON: the psum-gathered rounds keep the planes
-        # replicated-typed (no check_rep=False escape hatch)
-        return jax.jit(shard_map(
+        # replicated-typed (no check_vma=False escape hatch)
+        return jax.jit(jax.shard_map(
             inner, mesh=mesh,
             in_specs=(P(), P("data"), P("data"), P("data")),
             out_specs=(P(), P("data"), P("data"), P("data"))))
@@ -226,8 +223,8 @@ _SUBPROC = textwrap.dedent("""
         # observe every shard's copy of the (replicated) planes
         def inner(state):
             return jax.tree_util.tree_map(lambda x: x[None], tuple(state))
-        f = jax.jit(shard_map(inner, mesh=mesh, in_specs=(P(),),
-                              out_specs=P("data")))
+        f = jax.jit(jax.shard_map(inner, mesh=mesh, in_specs=(P(),),
+                                  out_specs=P("data")))
         return f(state)
 
     for engine in ("planes", "scan"):

@@ -270,24 +270,22 @@ def test_env_interpret_override(monkeypatch):
 
 
 def test_env_interpret_reaches_kernels(monkeypatch):
-    """With the env forcing interpret mode on CPU, every entry point still
-    routes and agrees with the oracle — the flag is plumbed end to end."""
-    from repro.kernels import ref, ring_enqueue
+    """With the env forcing interpret mode, the engine path's Pallas kernel
+    (``wavefaa``, the one a round runs) resolves to it through the round
+    runner and still agrees with the oracle — the flag is plumbed end to
+    end, while the ring waves run as plain XLA ops on every backend."""
+    from repro.kernels import LANES, ref, wavefaa
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "interpret")
-    nsl2, bot = 5, (1 << 31) - 1
-    nslots = 1 << nsl2
-    cyc = jnp.zeros(nslots, jnp.int32)
-    saf = jnp.ones(nslots, jnp.int32)
-    enq = jnp.zeros(nslots, jnp.int32)
-    idx = jnp.full(nslots, bot, jnp.int32)
-    tickets = jnp.arange(nslots, nslots + 8, dtype=jnp.int32)
-    values = jnp.arange(8, dtype=jnp.int32)
-    head = jnp.array([nslots], jnp.int32)
-    out = ring_enqueue(cyc, saf, enq, idx, tickets, values, head,
-                       nslots_log2=nsl2, idx_bot=bot)
-    want = ref.ring_enqueue_ref(cyc, saf, enq, idx, tickets, values, head,
-                                nsl2, bot)
-    for a, b in zip(out, want):
+    r = RoundRunner(_tree_step(), capacity_log2=8, batch=16)
+    assert r._engine.interpret is True
+    acc, _ = r.run([1], acc=jnp.zeros(80, jnp.int32))
+    want = np.zeros(80, np.int32)
+    want[1:64] = 1                                     # the tree 1..63
+    np.testing.assert_array_equal(np.asarray(acc), want)
+    active = jnp.asarray((np.arange(2 * LANES) % 3 == 0).astype(np.int32))
+    counter = jnp.array([7], jnp.int32)
+    for a, b in zip(wavefaa(active, counter), ref.wavefaa_ref(active,
+                                                              counter)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 

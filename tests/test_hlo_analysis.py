@@ -5,7 +5,7 @@ cost_analysis counts while bodies once)."""
 import jax
 import jax.numpy as jnp
 
-from repro.jaxcompat import cost_analysis_dict, make_mesh
+from repro.jaxcompat import make_mesh
 from repro.launch.hlo_analysis import analyze_hlo_text
 
 
@@ -28,19 +28,17 @@ def test_scan_trip_count_multiplies_flops():
     assert c.flops >= 2.5 * fwd, (c.flops, fwd)
     assert c.flops <= 4.0 * fwd, (c.flops, fwd)
     # cost_analysis counts the body once — the analyzer must exceed it
-    assert c.flops > float(cost_analysis_dict(compiled)["flops"]) * (L - 1) / 2
+    assert c.flops > float(compiled.cost_analysis()["flops"]) * (L - 1) / 2
 
 
 def test_collectives_counted():
     import numpy as np
     mesh = make_mesh((1,), ("data",))
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
-
     def f(x):
         return jax.lax.psum(x, "data")
 
-    g = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P()))
+    g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P()))
     compiled = g.lower(jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile()
     c = analyze_hlo_text(compiled.as_text())
     assert c.collective_bytes >= 0  # single device may elide the collective

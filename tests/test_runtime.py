@@ -159,7 +159,6 @@ def test_rounds_exactly_once_and_deterministic():
 def test_mesh_task_round_single_device():
     jax = pytest.importorskip("jax")
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.distqueue import dist_queue_init
@@ -172,10 +171,9 @@ def test_mesh_task_round_single_device():
         return mesh_task_round(state, values, emask, want, "data")
 
     # replication checker ON: the psum-gathered rounds keep the replicated
-    # planes replicated-typed (no check_rep=False escape hatch)
-    f = jax.jit(shard_map(inner, mesh=mesh,
-                          in_specs=(P(), P("data"), P("data"), P("data")),
-                          out_specs=(P(), P("data"), P("data"), P("data"))))
+    # planes replicated-typed (no check_vma=False escape hatch)
+    io = (P(), P("data"), P("data"), P("data"))
+    f = jax.jit(jax.shard_map(inner, mesh=mesh, in_specs=io, out_specs=io))
     state = dist_queue_init(16)
     vals = jnp.asarray([11, 12, 13, 14], jnp.int32)
     ones = jnp.ones(4, jnp.int32)

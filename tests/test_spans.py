@@ -252,7 +252,6 @@ def test_priority_class_rows_exact():
 
 
 def test_birth_stamps_survive_ticket_wraparound():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core.distqueue import (dist_claim_round, dist_publish_round,
                                       dist_queue_init)
@@ -279,10 +278,10 @@ def test_birth_stamps_survive_ticket_wraparound():
             bouts.append((ok, bout))
         return bouts[0] + bouts[1]
 
-    f = jax.jit(shard_map(inner, mesh=mesh,
-                          in_specs=(P(), P()),
-                          out_specs=(P(), P(), P(), P()),
-                          check_rep=False))
+    f = jax.jit(jax.shard_map(inner, mesh=mesh,
+                              in_specs=(P(), P()),
+                              out_specs=(P(), P(), P(), P()),
+                              check_vma=False))
     ok0, b0, ok1, b1 = f(state, births)
     assert bool(np.asarray(ok0).all()) and bool(np.asarray(ok1).all())
     np.testing.assert_array_equal(np.asarray(b0), np.full(b, 5))
