@@ -14,7 +14,9 @@ vectorized across the whole wave.  Lanes whose predicate fails (and inactive
 only installing/consuming lanes touch the planes.  The same vectorized
 plane updates are exposed as pure-jnp functions (``enq_planes`` /
 ``deq_planes``) so the fused round engine can inline them into a jitted
-``while_loop`` without a host round-trip.
+``while_loop`` without a host round-trip.  Each runs under a named scope
+(``repro.ring.enq`` / ``repro.ring.deq``) that the compiled ops carry in
+their ``op_name`` metadata, so a device trace tells the two waves apart.
 
 VMEM: the Pallas kernels hold the whole ring (4 × 2n × 4 B) plus the op
 batch in VMEM, in and out — 16 MiB of planes already at n = 256Ki, past
@@ -61,6 +63,7 @@ def cycle_lt(a, b, nslots_log2: int):
     return ((b - a) << nslots_log2) > 0
 
 
+@jax.named_scope("repro.ring.enq")
 def enq_planes(cycles, safes, enqs, idxs, tickets, values, head, *,
                nslots_log2: int, idx_bot: int, active=None,
                births=None, birth_round=None):
@@ -131,6 +134,7 @@ def enq_planes(cycles, safes, enqs, idxs, tickets, values, head, *,
     return cycles, safes, enqs, idxs, can.astype(jnp.int32), births
 
 
+@jax.named_scope("repro.ring.deq")
 def deq_planes(cycles, safes, enqs, idxs, tickets, *,
                nslots_log2: int, idx_bot: int, active=None, births=None,
                birth_packed: bool = False):

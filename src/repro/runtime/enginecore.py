@@ -39,6 +39,23 @@ Drain ordering at each host sync is fixed by the driver: trace plane
 first (``Telemetry.drain`` → ``heartbeat`` → ``finish``), span plane
 second (``Spans.drain`` → ``finish``) — registered once here, never
 re-threaded per engine.
+
+Phase names on the profiler's clock.  Every round body runs its phases
+under one vocabulary of ``jax.named_scope`` names, whatever kernel
+implements them: ``repro.ring.deq`` / ``repro.ring.enq`` (the ring
+waves), ``repro.heap.pop`` (with the claim schedule) /
+``repro.heap.insert`` (the heap waves), ``repro.step`` (the step
+function) and ``repro.publish`` (the mesh engines' one-psum exchange);
+``repro.wavefaa`` keeps its own scope, which names its Pallas call.  A
+scope reaches a compiled op only as its ``op_name`` metadata (a fusion
+carries its root op's), so ``megaround_hlo`` gives the compiled text that
+maps a trace's op names to phases.  The host steps of a run are
+``jax.profiler.TraceAnnotation`` spans: ``repro.seed`` (building and
+placing the seed carry), ``repro.dispatch`` (the megaround call),
+``repro.sync`` (the occupancy readback that waits for it) and
+``repro.drain`` (the trace/span drains, when a collector is on).  Both
+add metadata and host annotations only: the loop carry, the ops and the
+results are those of the unnamed loop.
 """
 
 from __future__ import annotations
@@ -60,6 +77,12 @@ from ..obs.trace import SyncPoint, Telemetry, trace_init, trace_record
 def _sds(shape, dtype=jnp.int32):
     """Shape-only leaf for registry declarations (no device allocation)."""
     return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _aval(x):
+    """Shape, dtype and placement of a megaround argument, for lowering
+    the same program again without holding its buffers."""
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
 
 
 class PlaneGroup(NamedTuple):
@@ -181,6 +204,8 @@ class EngineCore:
     # mesh engines: the shard_map in_specs of the megaround, from which the
     # chunk loop places the initial carry on the mesh (None = chip engine)
     _carry_specs = None
+    # argument avals of the last megaround call (``megaround_hlo``)
+    _megaround_avals = None
 
     def _reset(self) -> None:
         self.stats: Dict[str, int] = {}
@@ -358,19 +383,37 @@ class EngineCore:
                 lambda s: NamedSharding(self.mesh, s),
                 tuple(specs[:5]) + tuple(specs[6:]),
                 is_leaf=lambda s: isinstance(s, P))
-            placed = jax.device_put(tuple(state) + tuple(ext), shard)
+            with jax.profiler.TraceAnnotation("repro.seed"):
+                placed = jax.device_put(tuple(state) + tuple(ext), shard)
             state[:], ext[:] = placed[:5], placed[5:]
 
         def chunk_fn(limit):
-            out = self._megaround(*state, jnp.int32(limit), *ext)
+            args = (*state, jnp.int32(limit), *ext)
+            self._megaround_avals = jax.tree_util.tree_map(_aval, args)
+            with jax.profiler.TraceAnnotation("repro.dispatch"):
+                out = self._megaround(*args)
             state[:] = out[:5]
             oflow, r = out[5], out[6]
             ext[:] = out[7:]
-            occ = occ_fn(state[0])              # THE host sync
-            return (occ, int(r), bool(oflow), int(state[2]),
-                    int(state[3]), int(state[4]))
+            with jax.profiler.TraceAnnotation("repro.sync"):
+                occ = occ_fn(state[0])          # THE host sync
+                return (occ, int(r), bool(oflow), int(state[2]),
+                        int(state[3]), int(state[4]))
 
         self._drive(chunk_fn, max_rounds, what)
+
+    def megaround_hlo(self) -> str:
+        """The optimized HLO text of the megaround as the last chunk
+        called it: the instruction names a device trace shows, each with
+        the ``op_name`` metadata that holds its phase scope (module
+        docstring).  Compiles the program once more (a read of the
+        persistent compilation cache, where that is on), so call it
+        outside any timed window.  Raises ``RuntimeError`` before the
+        engine's first run."""
+        if self._megaround_avals is None:
+            raise RuntimeError("no megaround has run on this engine yet")
+        return self._megaround.lower(*self._megaround_avals).compile(
+        ).as_text()
 
     def _drive(self, chunk_fn, max_rounds: int, what: str) -> None:
         """``chunk_fn(limit)`` advances internal state by up to ``limit``
@@ -397,13 +440,15 @@ class EngineCore:
                 "host_syncs": host_syncs,
             }
             if self.telemetry is not None:
-                self.telemetry.drain(self._tel_plane(),
-                                     sync=host_syncs - 1, wall_time=now)
-                self.telemetry.heartbeat(point)
-                self.telemetry.finish(self.stats)
+                with jax.profiler.TraceAnnotation("repro.drain"):
+                    self.telemetry.drain(self._tel_plane(),
+                                         sync=host_syncs - 1, wall_time=now)
+                    self.telemetry.heartbeat(point)
+                    self.telemetry.finish(self.stats)
             if self.spans is not None:
-                self.spans.drain(self._span_plane(), wall_time=now)
-                self.spans.finish(self.stats)
+                with jax.profiler.TraceAnnotation("repro.drain"):
+                    self.spans.drain(self._span_plane(), wall_time=now)
+                    self.spans.finish(self.stats)
             if oflow:
                 raise RuntimeError(
                     f"{what} overflow: occupancy {occ} + spawned children "

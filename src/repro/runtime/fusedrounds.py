@@ -184,7 +184,8 @@ class RingEngine(EngineCore):
         cyc, saf, enq, idx, vals = deq[:5]
         ok = deq[5].astype(bool)
         head = head + k
-        acc, cvals, cmask = self.step_fn(acc, vals, ok)
+        with jax.named_scope("repro.step"):
+            acc, cvals, cmask = self.step_fn(acc, vals, ok)
         cm = jnp.broadcast_to(cmask.astype(bool), cvals.shape).reshape(-1)
         cv = cvals.reshape(-1).astype(jnp.int32)
         # dense-wave rule (DESIGN.md § 4.4): compact the sparse child
@@ -205,8 +206,9 @@ class RingEngine(EngineCore):
             # children in wavefaa rank order, so tickets are the
             # contiguous run tail + [0, n_child) — bit-identical
             # (ticket, value) scatters to the sparse install
-            (cv,), n_child = compact_planes(cm.astype(jnp.int32), (cv,),
-                                            width=wdth)
+            with jax.named_scope("repro.ring.enq"):
+                (cv,), n_child = compact_planes(cm.astype(jnp.int32), (cv,),
+                                                width=wdth)
             over = (tail + n_child - head) > capacity
             lane_w = jnp.arange(wdth, dtype=jnp.int32)
             etickets = jnp.where((lane_w < n_child) & ~over,
@@ -257,18 +259,20 @@ class RingEngine(EngineCore):
         flagged round, so stats reflect the partial run).  Returns
         ``(acc, final RingState)``."""
         self._reset()
-        st = self._seed(ring_init(self.capacity_log2),
-                        np.asarray(initial, np.int32).reshape(-1))
-        acc = jax.tree_util.tree_map(jnp.asarray, acc)
-        q = RingState(st.cycles, st.safes, st.enqs, st.idxs,
-                      jnp.int32(st.head), jnp.int32(st.tail))
-        state = [q, acc, jnp.int32(0), jnp.int32(0),    # processed/spawned
-                 jnp.int32(st.tail - st.head)]          # max_occ
-        # obs state: [TracePlane, SpanPlane, births] — None slots are empty
-        # pytrees, so the all-None call is the exact unspanned graph.  The
-        # FIFO ring keeps births=None: its stamps pack into the enq-flag
-        # plane (seeds installed by the kernel carry flag 1 ⇔ birth 0)
-        ext = [self._tel_init(), self._span_init(), None]
+        with jax.profiler.TraceAnnotation("repro.seed"):
+            st = self._seed(ring_init(self.capacity_log2),
+                            np.asarray(initial, np.int32).reshape(-1))
+            acc = jax.tree_util.tree_map(jnp.asarray, acc)
+            q = RingState(st.cycles, st.safes, st.enqs, st.idxs,
+                          jnp.int32(st.head), jnp.int32(st.tail))
+            state = [q, acc, jnp.int32(0), jnp.int32(0),  # processed/spawned
+                     jnp.int32(st.tail - st.head)]        # max_occ
+            # obs state: [TracePlane, SpanPlane, births] — None slots are
+            # empty pytrees, so the all-None call is the exact unspanned
+            # graph.  The FIFO ring keeps births=None: its stamps pack into
+            # the enq-flag plane (seeds installed by the kernel carry flag
+            # 1 ⇔ birth 0)
+            ext = [self._tel_init(), self._span_init(), None]
         self._run_chunks(state, ext, lambda q: int(q.tail - q.head),
                          "ring", max_rounds)
         q, acc = state[0], state[1]
@@ -324,11 +328,13 @@ class HeapEngine(EngineCore):
         k = jnp.minimum(jnp.int32(batch), size)
         pop_ops = jnp.where(lane < k, OP_DELMIN, OP_NOP)
         # with spans the births plane rides every sift as the rider plane
-        pop = heap_planes(keys, vals, size, pop_ops, pad, pad,
-                          cap_log2=cap_log2, arity_log2=arity_log2,
-                          rider=births)
+        with jax.named_scope("repro.heap.pop"):
+            pop = heap_planes(keys, vals, size, pop_ops, pad, pad,
+                              cap_log2=cap_log2, arity_log2=arity_log2,
+                              rider=births)
         keys, vals, size, outk, outv, ok = pop[:6]
-        acc, ckeys, cvals, cmask = self.step_fn(acc, outk, outv, ok)
+        with jax.named_scope("repro.step"):
+            acc, ckeys, cvals, cmask = self.step_fn(acc, outk, outv, ok)
         cm = jnp.broadcast_to(cmask.astype(bool), ckeys.shape).reshape(-1)
         ckf = ckeys.reshape(-1).astype(jnp.int32)
         cvf = cvals.reshape(-1).astype(jnp.int32)
@@ -342,16 +348,18 @@ class HeapEngine(EngineCore):
             over = size + n_child > capacity
             ins_ops = jnp.where(cm & ~over, OP_INSERT, OP_NOP)
         else:
-            (ckf, cvf), n_child = compact_planes(
-                cm.astype(jnp.int32), (ckf, cvf), width=wdth)
+            with jax.named_scope("repro.heap.insert"):
+                (ckf, cvf), n_child = compact_planes(
+                    cm.astype(jnp.int32), (ckf, cvf), width=wdth)
             over = size + n_child > capacity
             lane_w = jnp.arange(wdth, dtype=jnp.int32)
             ins_ops = jnp.where((lane_w < n_child) & ~over,
                                 OP_INSERT, OP_NOP)
-        ins = heap_planes(keys, vals, size, ins_ops, ckf, cvf,
-                          cap_log2=cap_log2, arity_log2=arity_log2,
-                          rider=pop[6] if sps else None,
-                          oprider=sp.round if sps else None)
+        with jax.named_scope("repro.heap.insert"):
+            ins = heap_planes(keys, vals, size, ins_ops, ckf, cvf,
+                              cap_log2=cap_log2, arity_log2=arity_log2,
+                              rider=pop[6] if sps else None,
+                              oprider=sp.round if sps else None)
         keys, vals, size = ins[:3]
         total = jnp.where(over, 0, n_child)
         telinfo = None
@@ -395,13 +403,14 @@ class HeapEngine(EngineCore):
         ik = np.asarray(initial_keys, np.int32).reshape(-1)
         iv = np.asarray(initial_vals, np.int32).reshape(-1)
         assert ik.shape == iv.shape
-        st = self._seed(heap_init(self.capacity_log2), ik, iv)
-        acc = jax.tree_util.tree_map(jnp.asarray, acc)
-        q = HeapState(st.keys, st.vals, jnp.asarray(st.size, jnp.int32))
-        state = [q, acc, jnp.int32(0), jnp.int32(0),    # processed/spawned
-                 jnp.int32(st.size)]                    # max_occ
-        ext = [self._tel_init(), self._span_init(),
-               self._births_init((self.capacity,))]
+        with jax.profiler.TraceAnnotation("repro.seed"):
+            st = self._seed(heap_init(self.capacity_log2), ik, iv)
+            acc = jax.tree_util.tree_map(jnp.asarray, acc)
+            q = HeapState(st.keys, st.vals, jnp.asarray(st.size, jnp.int32))
+            state = [q, acc, jnp.int32(0), jnp.int32(0),  # processed/spawned
+                     jnp.int32(st.size)]                  # max_occ
+            ext = [self._tel_init(), self._span_init(),
+                   self._births_init((self.capacity,))]
         self._run_chunks(state, ext, lambda q: int(q.size),
                          "heap", max_rounds)
         q = state[0]

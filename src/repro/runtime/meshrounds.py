@@ -232,8 +232,9 @@ class MeshRingEngine(_MeshFifoBase):
         sps = sp is not None
         occ = state.tail - state.head
         k = jnp.minimum(occ, jnp.int32(self.shards * self.batch))
-        cr = dist_claim_round(state, k, self.batch, self.axis,
-                              with_grid=tel, births=births)
+        with jax.named_scope("repro.ring.deq"):
+            cr = dist_claim_round(state, k, self.batch, self.axis,
+                                  with_grid=tel, births=births)
         state, vals, ok = cr[0], cr[1], cr[2]
         i = 3
         if tel:
@@ -241,7 +242,8 @@ class MeshRingEngine(_MeshFifoBase):
             i += 1
         if sps:
             bout = cr[i]
-        acc, cvals, cmask = self.step_fn(acc, vals, ok)
+        with jax.named_scope("repro.step"):
+            acc, cvals, cmask = self.step_fn(acc, vals, ok)
         cm = jnp.broadcast_to(cmask.astype(bool), cvals.shape).reshape(-1)
         cv = cvals.reshape(-1).astype(jnp.int32)
         # dense-wave rule (DESIGN.md § 4.4): each shard compacts its child
@@ -249,16 +251,17 @@ class MeshRingEngine(_MeshFifoBase):
         # psum, O(width) instead of O(B·F) payload, bit-identical planes.
         # The decision is static (trace-time): exactly one path compiles.
         wdth = compact_width(cv.shape[0], self.capacity, self.compact)
-        if wdth is None:
-            pr = dist_publish_round(
-                state, cv, cm.astype(jnp.int32), self.axis,
-                capacity=self.capacity, with_counts=tel, births=births,
-                birth_round=sp.round if sps else None)
-        else:
-            pr = dist_publish_compact_round(
-                state, cv, cm.astype(jnp.int32), self.axis,
-                capacity=self.capacity, width=wdth, with_counts=tel,
-                births=births, birth_round=sp.round if sps else None)
+        with jax.named_scope("repro.publish"):
+            if wdth is None:
+                pr = dist_publish_round(
+                    state, cv, cm.astype(jnp.int32), self.axis,
+                    capacity=self.capacity, with_counts=tel, births=births,
+                    birth_round=sp.round if sps else None)
+            else:
+                pr = dist_publish_compact_round(
+                    state, cv, cm.astype(jnp.int32), self.axis,
+                    capacity=self.capacity, width=wdth, with_counts=tel,
+                    births=births, birth_round=sp.round if sps else None)
         state, _, total, over = pr[0], pr[1], pr[2], pr[3]
         j = 4
         telinfo = None
@@ -302,14 +305,15 @@ class MeshRingEngine(_MeshFifoBase):
         planes, head/tail, stats.  Raises ``RuntimeError`` on ring
         overflow or truncation at the next sync."""
         self._reset()
-        st = self._seed(dist_queue_init(self.capacity),
-                        np.asarray(initial, np.int32).reshape(-1))
-        st, acc = self._initial_carry(st, acc)
-        occ0 = jnp.int32(np.asarray(st.tail - st.head))
-        state = [st, acc, jnp.int32(0), jnp.int32(0), occ0]
-        ext = [self._tel_init(self.shards),
-               self._span_init(self.shards, stacked=True),
-               self._births_init((2 << self.capacity_log2,))]
+        with jax.profiler.TraceAnnotation("repro.seed"):
+            st = self._seed(dist_queue_init(self.capacity),
+                            np.asarray(initial, np.int32).reshape(-1))
+            st, acc = self._initial_carry(st, acc)
+            occ0 = jnp.int32(np.asarray(st.tail - st.head))
+            state = [st, acc, jnp.int32(0), jnp.int32(0), occ0]
+            ext = [self._tel_init(self.shards),
+                   self._span_init(self.shards, stacked=True),
+                   self._births_init((2 << self.capacity_log2,))]
         self._run_chunks(
             state, ext,
             lambda q: int(np.int32(np.asarray(q.tail - q.head))),
@@ -416,21 +420,24 @@ class ShardedMeshRingEngine(_MeshFifoBase):
         replicated, so with telemetry on they ride the publish psum as
         ``pop_meta`` words — one-collective-per-round still holds."""
         planes = (state.cycles, state.safes, state.enqs, state.idxs)
-        planes, heads, vals, ok, counts = dist_sharded_claim_round(
-            planes, state.heads, state.tails, self.batch, self.axis,
-            nslots_log2=self.lslots_log2)
-        acc, cvals, cmask = self.step_fn(acc, vals, ok)
+        with jax.named_scope("repro.ring.deq"):
+            planes, heads, vals, ok, counts = dist_sharded_claim_round(
+                planes, state.heads, state.tails, self.batch, self.axis,
+                nslots_log2=self.lslots_log2)
+        with jax.named_scope("repro.step"):
+            acc, cvals, cmask = self.step_fn(acc, vals, ok)
         cm = jnp.broadcast_to(cmask.astype(bool), cvals.shape).reshape(-1)
         cv = cvals.reshape(-1).astype(jnp.int32)
         pop_meta = masked_min_max(vals, ok) if tel else None
         # dense-wave bound: a round spawning more than the GLOBAL capacity
         # must overflow some local ring, where both paths install nothing
         wdth = compact_width(cv.shape[0], self.capacity, self.compact)
-        res = dist_sharded_publish_round(
-            planes, heads, state.tails, cv, cm.astype(jnp.int32),
-            self.axis, nslots_log2=self.lslots_log2,
-            local_capacity=self.local_capacity, width=wdth,
-            pop_meta=pop_meta)
+        with jax.named_scope("repro.publish"):
+            res = dist_sharded_publish_round(
+                planes, heads, state.tails, cv, cm.astype(jnp.int32),
+                self.axis, nslots_log2=self.lslots_log2,
+                local_capacity=self.local_capacity, width=wdth,
+                pop_meta=pop_meta)
         planes, tails, total, over = res[0], res[1], res[2], res[3]
         state = DistShardedQueueState(*planes, tails=tails, heads=heads)
         telinfo = None
@@ -462,12 +469,14 @@ class ShardedMeshRingEngine(_MeshFifoBase):
         replicated engine.  Returns (acc, final ``DistShardedQueueState``
         with globally-stacked planes)."""
         self._reset()
-        st = self._seed(dist_sharded_queue_init(self.capacity, self.shards),
-                        np.asarray(initial, np.int32).reshape(-1))
-        st, acc = self._initial_carry(st, acc)
-        occ0 = jnp.int32(int(np.asarray(st.tails - st.heads).sum()))
-        state = [st, acc, jnp.int32(0), jnp.int32(0), occ0]
-        ext = [self._tel_init(self.shards), None, None]
+        with jax.profiler.TraceAnnotation("repro.seed"):
+            st = self._seed(
+                dist_sharded_queue_init(self.capacity, self.shards),
+                np.asarray(initial, np.int32).reshape(-1))
+            st, acc = self._initial_carry(st, acc)
+            occ0 = jnp.int32(int(np.asarray(st.tails - st.heads).sum()))
+            state = [st, acc, jnp.int32(0), jnp.int32(0), occ0]
+            ext = [self._tel_init(self.shards), None, None]
         self._run_chunks(
             state, ext,
             lambda q: int(np.asarray(q.tails - q.heads).sum()),
@@ -701,26 +710,30 @@ class _PriorityMeshBase(EngineCore):
         sps = sp is not None
         spl = self.split
         me = jax.lax.axis_index(self.axis)
-        counts = priority_claim_schedule(jnp.sum(sizes), self.shards,
-                                         self.batch, hints, sizes)
-        if sps or spl:
-            # the rider plane carries birth stamps (spans) or the split
-            # aux words — mutually exclusive by construction
-            keys, vals, size, outk, outv, ok, births, bout = heap_pop_count(
-                keys, vals, sizes[me], counts[me], batch=self.batch,
-                cap_log2=self.capacity_log2, arity_log2=self.arity_log2,
-                rider=births)
-        else:
-            keys, vals, size, outk, outv, ok = heap_pop_count(
-                keys, vals, sizes[me], counts[me], batch=self.batch,
-                cap_log2=self.capacity_log2, arity_log2=self.arity_log2)
-        if spl:
-            acc, ckeys, cvals, caux, cmask = self.step_fn(
-                acc, outk, outv, bout, ok)
-            caf = caux.reshape(-1).astype(jnp.int32)
-        else:
-            acc, ckeys, cvals, cmask = self.step_fn(acc, outk, outv, ok)
-            caf = None
+        with jax.named_scope("repro.heap.pop"):
+            counts = priority_claim_schedule(jnp.sum(sizes), self.shards,
+                                             self.batch, hints, sizes)
+            if sps or spl:
+                # the rider plane carries birth stamps (spans) or the split
+                # aux words — mutually exclusive by construction
+                (keys, vals, size, outk, outv, ok, births,
+                 bout) = heap_pop_count(
+                    keys, vals, sizes[me], counts[me], batch=self.batch,
+                    cap_log2=self.capacity_log2,
+                    arity_log2=self.arity_log2, rider=births)
+            else:
+                keys, vals, size, outk, outv, ok = heap_pop_count(
+                    keys, vals, sizes[me], counts[me], batch=self.batch,
+                    cap_log2=self.capacity_log2,
+                    arity_log2=self.arity_log2)
+        with jax.named_scope("repro.step"):
+            if spl:
+                acc, ckeys, cvals, caux, cmask = self.step_fn(
+                    acc, outk, outv, bout, ok)
+                caf = caux.reshape(-1).astype(jnp.int32)
+            else:
+                acc, ckeys, cvals, cmask = self.step_fn(acc, outk, outv, ok)
+                caf = None
         cm = jnp.broadcast_to(cmask.astype(bool), ckeys.shape).reshape(-1)
         ckf = ckeys.reshape(-1).astype(jnp.int32)
         cvf = cvals.reshape(-1).astype(jnp.int32)
@@ -731,14 +744,15 @@ class _PriorityMeshBase(EngineCore):
         # shard's heap, where both paths install nothing
         wdth = compact_width(ckf.shape[0], self.shards * self.capacity,
                              self.compact)
-        if wdth is None:
-            res = dist_priority_publish_round(
-                ckf, cvf, cm.astype(jnp.int32), jnp.min(keys), size,
-                self.axis, pop_meta=pop_meta, aux=caf)
-        else:
-            res = dist_priority_publish_compact_round(
-                ckf, cvf, cm.astype(jnp.int32), jnp.min(keys), size,
-                self.axis, width=wdth, pop_meta=pop_meta, aux=caf)
+        with jax.named_scope("repro.publish"):
+            if wdth is None:
+                res = dist_priority_publish_round(
+                    ckf, cvf, cm.astype(jnp.int32), jnp.min(keys), size,
+                    self.axis, pop_meta=pop_meta, aux=caf)
+            else:
+                res = dist_priority_publish_compact_round(
+                    ckf, cvf, cm.astype(jnp.int32), jnp.min(keys), size,
+                    self.axis, width=wdth, pop_meta=pop_meta, aux=caf)
         gk, gv = res[0], res[1]
         i = 2
         if spl:
@@ -762,15 +776,18 @@ class _PriorityMeshBase(EngineCore):
                         + (s_ix < total % self.shards).astype(jnp.int32))
         over = jnp.any(sizes_pop + assigned > self.capacity)
         mine = gactive & (shard_of == me) & ~over
-        if sps or spl:
-            keys, vals, size, _, _, _, births, _ = heap_insert_masked(
-                keys, vals, size, gk, gv, mine,
-                cap_log2=self.capacity_log2, arity_log2=self.arity_log2,
-                rider=births, oprider=gaux if spl else sp.round)
-        else:
-            keys, vals, size, _, _, _ = heap_insert_masked(
-                keys, vals, size, gk, gv, mine,
-                cap_log2=self.capacity_log2, arity_log2=self.arity_log2)
+        with jax.named_scope("repro.heap.insert"):
+            if sps or spl:
+                keys, vals, size, _, _, _, births, _ = heap_insert_masked(
+                    keys, vals, size, gk, gv, mine,
+                    cap_log2=self.capacity_log2,
+                    arity_log2=self.arity_log2,
+                    rider=births, oprider=gaux if spl else sp.round)
+            else:
+                keys, vals, size, _, _, _ = heap_insert_masked(
+                    keys, vals, size, gk, gv, mine,
+                    cap_log2=self.capacity_log2,
+                    arity_log2=self.arity_log2)
         ckmin = (jnp.full((self.shards + 1,), HEAP_KEY_INF, jnp.int32)
                  .at[shard_of].min(jnp.where(gactive, gk, HEAP_KEY_INF))
                  )[:self.shards]
@@ -806,43 +823,48 @@ class _PriorityMeshBase(EngineCore):
         me = jax.lax.axis_index(self.axis)
         sb = self.shards * self.batch
         k = jnp.minimum(size, jnp.int32(sb))
-        if sps or spl:
-            keys, vals, size, outk, outv, _, births, outb = heap_pop_count(
-                keys, vals, size, k, batch=sb,
-                cap_log2=self.capacity_log2, arity_log2=self.arity_log2,
-                rider=births)
-        else:
-            keys, vals, size, outk, outv, _ = heap_pop_count(
-                keys, vals, size, k, batch=sb,
-                cap_log2=self.capacity_log2, arity_log2=self.arity_log2)
-        active, ranks = claim_schedule(k, self.shards, self.batch)
-        act_l = active.reshape(self.shards, self.batch)[me]
-        rk_l = ranks.reshape(self.shards, self.batch)[me]
-        outk_l = jnp.where(act_l, outk[rk_l], HEAP_KEY_INF)
-        outv_l = jnp.where(act_l, outv[rk_l], -1)
-        if spl:
-            outa_l = jnp.where(act_l, outb[rk_l], 0)
-            acc, ckeys, cvals, caux, cmask = self.step_fn(
-                acc, outk_l, outv_l, outa_l, act_l)
-            caf = caux.reshape(-1).astype(jnp.int32)
-        else:
-            acc, ckeys, cvals, cmask = self.step_fn(acc, outk_l, outv_l,
-                                                    act_l)
-            caf = None
+        with jax.named_scope("repro.heap.pop"):
+            if sps or spl:
+                (keys, vals, size, outk, outv, _, births,
+                 outb) = heap_pop_count(
+                    keys, vals, size, k, batch=sb,
+                    cap_log2=self.capacity_log2,
+                    arity_log2=self.arity_log2, rider=births)
+            else:
+                keys, vals, size, outk, outv, _ = heap_pop_count(
+                    keys, vals, size, k, batch=sb,
+                    cap_log2=self.capacity_log2,
+                    arity_log2=self.arity_log2)
+            active, ranks = claim_schedule(k, self.shards, self.batch)
+            act_l = active.reshape(self.shards, self.batch)[me]
+            rk_l = ranks.reshape(self.shards, self.batch)[me]
+            outk_l = jnp.where(act_l, outk[rk_l], HEAP_KEY_INF)
+            outv_l = jnp.where(act_l, outv[rk_l], -1)
+        with jax.named_scope("repro.step"):
+            if spl:
+                outa_l = jnp.where(act_l, outb[rk_l], 0)
+                acc, ckeys, cvals, caux, cmask = self.step_fn(
+                    acc, outk_l, outv_l, outa_l, act_l)
+                caf = caux.reshape(-1).astype(jnp.int32)
+            else:
+                acc, ckeys, cvals, cmask = self.step_fn(acc, outk_l, outv_l,
+                                                        act_l)
+                caf = None
         cm = jnp.broadcast_to(cmask.astype(bool), ckeys.shape).reshape(-1)
         ckf = ckeys.reshape(-1).astype(jnp.int32)
         cvf = cvals.reshape(-1).astype(jnp.int32)
         # dense-wave rule (DESIGN.md § 4.4): the strict install bound is
         # the replicated heap's capacity
         wdth = compact_width(ckf.shape[0], self.capacity, self.compact)
-        if wdth is None:
-            res = dist_priority_publish_round(
-                ckf, cvf, cm.astype(jnp.int32), jnp.min(keys), size,
-                self.axis, aux=caf)
-        else:
-            res = dist_priority_publish_compact_round(
-                ckf, cvf, cm.astype(jnp.int32), jnp.min(keys), size,
-                self.axis, width=wdth, aux=caf)
+        with jax.named_scope("repro.publish"):
+            if wdth is None:
+                res = dist_priority_publish_round(
+                    ckf, cvf, cm.astype(jnp.int32), jnp.min(keys), size,
+                    self.axis, aux=caf)
+            else:
+                res = dist_priority_publish_compact_round(
+                    ckf, cvf, cm.astype(jnp.int32), jnp.min(keys), size,
+                    self.axis, width=wdth, aux=caf)
         gk, gv = res[0], res[1]
         i = 2
         if spl:
@@ -851,15 +873,18 @@ class _PriorityMeshBase(EngineCore):
         gactive, total = res[i], res[i + 2]
         over = (size + total) > jnp.int32(self.capacity)
         ins = gactive & ~over
-        if sps or spl:
-            keys, vals, size, _, _, _, births, _ = heap_insert_masked(
-                keys, vals, size, gk, gv, ins,
-                cap_log2=self.capacity_log2, arity_log2=self.arity_log2,
-                rider=births, oprider=gaux if spl else sp.round)
-        else:
-            keys, vals, size, _, _, _ = heap_insert_masked(
-                keys, vals, size, gk, gv, ins,
-                cap_log2=self.capacity_log2, arity_log2=self.arity_log2)
+        with jax.named_scope("repro.heap.insert"):
+            if sps or spl:
+                keys, vals, size, _, _, _, births, _ = heap_insert_masked(
+                    keys, vals, size, gk, gv, ins,
+                    cap_log2=self.capacity_log2,
+                    arity_log2=self.arity_log2,
+                    rider=births, oprider=gaux if spl else sp.round)
+            else:
+                keys, vals, size, _, _, _ = heap_insert_masked(
+                    keys, vals, size, gk, gv, ins,
+                    cap_log2=self.capacity_log2,
+                    arity_log2=self.arity_log2)
         total = jnp.where(over, 0, total)
         telinfo = None
         if tel:
@@ -998,21 +1023,22 @@ class MeshHeapEngine(_PriorityMeshBase):
             assert ia.shape == ik.shape
         else:
             ia = None
-        acc = self._broadcast_acc(acc)
-        seeded = self._seed(ik, iv, ia)
-        if self.relaxed:
-            qstate = seeded[:4]
-            occ0 = jnp.int32(int(np.asarray(qstate[2]).sum()))
-            births0 = (seeded[4] if spl
-                       else self._births_init((self.shards, self.capacity)))
-        else:
-            qstate = DistHeapState(*seeded[:3])
-            occ0 = jnp.asarray(qstate.size, jnp.int32)
-            births0 = (seeded[3] if spl
-                       else self._births_init((self.capacity,)))
-        state = [qstate, acc, jnp.int32(0), jnp.int32(0), occ0]
-        ext = [self._tel_init(self.shards),
-               self._span_init(self.shards, stacked=True), births0]
+        with jax.profiler.TraceAnnotation("repro.seed"):
+            acc = self._broadcast_acc(acc)
+            seeded = self._seed(ik, iv, ia)
+            if self.relaxed:
+                qstate = seeded[:4]
+                occ0 = jnp.int32(int(np.asarray(qstate[2]).sum()))
+                births0 = (seeded[4] if spl else
+                           self._births_init((self.shards, self.capacity)))
+            else:
+                qstate = DistHeapState(*seeded[:3])
+                occ0 = jnp.asarray(qstate.size, jnp.int32)
+                births0 = (seeded[3] if spl
+                           else self._births_init((self.capacity,)))
+            state = [qstate, acc, jnp.int32(0), jnp.int32(0), occ0]
+            ext = [self._tel_init(self.shards),
+                   self._span_init(self.shards, stacked=True), births0]
 
         def occ_fn(q):
             return (int(np.asarray(q[2]).sum()) if self.relaxed

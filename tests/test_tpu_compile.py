@@ -162,3 +162,57 @@ def test_sssp_mesh_compiles_on_four_chips(mesh4):
                                      s(()), None, None, s((4, cap))))
     text = eng._megaround.lower(*avals).compile().as_text()
     assert MOSAIC not in text and "all-reduce" in text
+
+
+def _v5e_phase_text(kind, topo):
+    """Optimized v5e text of a small megaround: BFS on the chip ring
+    (``RingEngine``) or relaxed split-payload SSSP on a one-chip mesh
+    (``MeshHeapEngine``, one shard)."""
+    from repro.apps import bfs, sssp
+    g = bfs.road_like(64 * 64)
+    if kind == "bfs_ring":
+        from repro.runtime.fusedrounds import RingState
+        s = _on(SingleDeviceSharding(topo.devices[0]))
+        runner, init = bfs.bfs_rounds_runner(g, batch=64, interpret=False)
+        eng = runner._engine
+        ns = 2 << eng.capacity_log2
+        acc = jax.tree_util.tree_map(lambda x: s(x.shape), init(0))
+        q = RingState(s((ns,)), s((ns,)), s((ns,)), s((ns,)), s(()), s(()))
+        return eng._megaround.lower(q, acc, s(()), s(()), s(()),
+                                    s(())).compile().as_text()
+    mesh1 = Mesh(np.array(topo.devices[:1]), ("data",),
+                 axis_types=(AxisType.Auto,))
+    s = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    runner, _ = sssp.sssp_mesh_rounds_runner(
+        g, sssp.with_weights(g, max_w=8), mesh=mesh1, batch=64,
+        relaxed=True, split_payload=True)
+    eng = runner._engine
+    cap = eng.capacity
+    q = (s((1, cap)), s((1, cap)), s((1,)), s((1,)))
+    avals = _mesh_avals(eng, mesh1, (q, s((1, g.n)), s(()), s(()), s(()),
+                                     s(()), None, None, s((1, cap))))
+    return eng._megaround.lower(*avals).compile().as_text()
+
+
+@pytest.mark.parametrize("kind,scopes", [
+    ("bfs_ring", {"repro.ring.deq", "repro.ring.enq", "repro.step",
+                  "repro.wavefaa"}),
+    ("sssp_relaxed_1_shard", {"repro.heap.pop", "repro.heap.insert",
+                              "repro.step", "repro.publish"})])
+def test_phase_scopes_reach_the_v5e_megaround(topo, kind, scopes):
+    """Every phase scope is in the compiled instructions' ``op_name``;
+    the Pallas call keeps the HLO name ``repro.wavefaa.N`` that the
+    benchmark's ``wavefaa`` readers match; and on one chip XLA drops the
+    one-device publish psum, so the SSSP cell's ``repro.publish`` holds
+    only the packing and ranking around it."""
+    import re
+    text = _v5e_phase_text(kind, topo)
+    found = {seg for path in re.findall(r'op_name="([^"]*)"', text)
+             for seg in path.split("/") if seg.startswith("repro.")}
+    assert found == scopes
+    calls = re.findall(r"%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+                       text)
+    if kind == "bfs_ring":
+        assert len(calls) == 1 and calls[0].startswith("repro.wavefaa.")
+    else:
+        assert calls == [] and "all-reduce" not in text
