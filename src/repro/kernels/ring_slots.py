@@ -13,10 +13,24 @@ vectorized across the whole wave.  Lanes whose predicate fails (and inactive
 ``ticket == -1`` lanes) are routed to an out-of-range index and dropped, so
 only installing/consuming lanes touch the planes.  The same vectorized
 plane updates are exposed as pure-jnp functions (``enq_planes`` /
-``deq_planes``) so the fused round engine can inline them into a jitted
-``while_loop`` without a host round-trip.  Each runs under a named scope
-(``repro.ring.enq`` / ``repro.ring.deq``) that the compiled ops carry in
-their ``op_name`` metadata, so a device trace tells the two waves apart.
+``deq_planes``) so the round engines can inline them into a jitted
+``while_loop`` without a host round-trip.
+
+Window waves.  On a v5e the plane scatters and gathers into a 2^24-slot
+ring cost about 96 ns a scatter lane and 27 ns a gather lane, dropped
+lanes included (PERF.md § 5; the cause is open).  A wave whose tickets
+are one contiguous run (the chip ring's dequeue ``head + [0, k)`` and enqueue ``tail +
+[0, n)``) touches a window of slots that lies inside two aligned blocks
+of ``Bk = window_width(width)`` slots, so ``deq_window`` / ``enq_window``
+read those blocks (``dynamic_slice``), run the same predicates over the
+2Bk-lane window, and write the blocks back (``dynamic_update_slice``, in
+place): the same planes and results as the plane functions on the same
+tickets, bit for bit, with no per-lane scatter or gather.  They need
+``Bk <= n``.
+
+Each wave function runs under a named scope (``repro.ring.enq`` /
+``repro.ring.deq``) that the compiled ops carry in their ``op_name``
+metadata, so a device trace tells the two waves apart.
 
 VMEM: the Pallas kernels hold the whole ring (4 × 2n × 4 B) plus the op
 batch in VMEM, in and out — 16 MiB of planes already at n = 256Ki, past
@@ -61,6 +75,22 @@ def cycle_lt(a, b, nslots_log2: int):
     within half the cycle modulus of each other — guaranteed because a
     ring holds at most two live cycles at once (Lemma III.2)."""
     return ((b - a) << nslots_log2) > 0
+
+
+def _install_flag(birth_round):
+    """The enq-flag word an install writes: 1, or with a packed birth stamp
+    ``(birth_round << 1) | 1`` (``enq_planes`` docstring).  Raises for a
+    concrete round past ``SPAN_ROUND_CAP``."""
+    if birth_round is None:
+        return jnp.int32(1)
+    if not isinstance(birth_round, jax.core.Tracer):
+        if int(birth_round) >= SPAN_ROUND_CAP:
+            raise ValueError(
+                f"birth_round {int(birth_round)} exceeds the packed "
+                f"birth-stamp cap SPAN_ROUND_CAP={SPAN_ROUND_CAP}: the "
+                f"(birth << 1) | 1 layout caps the round clock at 2^30 "
+                f"(use the separate births plane for longer clocks)")
+    return (jnp.asarray(birth_round, jnp.int32) << 1) | 1
 
 
 @jax.named_scope("repro.ring.enq")
@@ -114,17 +144,7 @@ def enq_planes(cycles, safes, enqs, idxs, tickets, values, head, *,
     w = jnp.where(can, j, nslots)          # failed lanes scatter out of range
     cycles = cycles.at[w].set(c, mode="drop")
     safes = safes.at[w].set(1, mode="drop")
-    if births is None and birth_round is not None:
-        if not isinstance(birth_round, jax.core.Tracer):
-            if int(birth_round) >= SPAN_ROUND_CAP:
-                raise ValueError(
-                    f"birth_round {int(birth_round)} exceeds the packed "
-                    f"birth-stamp cap SPAN_ROUND_CAP={SPAN_ROUND_CAP}: the "
-                    f"(birth << 1) | 1 layout caps the round clock at 2^30 "
-                    f"(use the separate births plane for longer clocks)")
-        flag = (jnp.asarray(birth_round, jnp.int32) << 1) | 1
-    else:
-        flag = jnp.int32(1)
+    flag = _install_flag(birth_round if births is None else None)
     enqs = enqs.at[w].set(flag, mode="drop")
     idxs = idxs.at[w].set(values, mode="drop")
     if births is None:
@@ -173,6 +193,110 @@ def deq_planes(cycles, safes, enqs, idxs, tickets, *,
         return cycles, safes, enqs, idxs, vals, hit.astype(jnp.int32)
     bvals = jnp.where(hit, births[j], -1)
     return cycles, safes, enqs, idxs, vals, hit.astype(jnp.int32), bvals
+
+
+def window_width(lanes: int) -> int:
+    """Bk, the block of a window wave over ``lanes`` contiguous tickets:
+    the least power of two >= ``lanes``."""
+    return 1 << max(int(lanes) - 1, 0).bit_length()
+
+
+def _window_read(planes, start, bk: int, nslots_log2: int):
+    """The two aligned ``bk``-slot blocks that hold the slots of tickets
+    ``[start, start + bk)`` mod 2n, as one (2bk,) window per plane.  Block
+    starts are multiples of ``bk`` below 2n, so no ``dynamic_slice`` clamp
+    can shift them.  Returns (windows, block starts, offset of ``start``
+    in the window)."""
+    nslots = 1 << nslots_log2
+    s = start & (nslots - 1)
+    b0 = s & ~(bk - 1)
+    b1 = (b0 + bk) & (nslots - 1)
+    wins = tuple(jnp.concatenate([jax.lax.dynamic_slice(p, (b0,), (bk,)),
+                                  jax.lax.dynamic_slice(p, (b1,), (bk,))])
+                 for p in planes)
+    return wins, (b0, b1), s & (bk - 1)
+
+
+def _window_write(plane, win, blocks):
+    b0, b1 = blocks
+    bk = win.shape[0] // 2
+    plane = jax.lax.dynamic_update_slice(plane, win[:bk], (b0,))
+    return jax.lax.dynamic_update_slice(plane, win[bk:], (b1,))
+
+
+@jax.named_scope("repro.ring.deq")
+def deq_window(cycles, safes, enqs, idxs, head, k, *, batch: int,
+               nslots_log2: int, idx_bot: int, birth_packed: bool = False):
+    """``deq_planes`` for the wave of tickets ``head + [0, k)``, ``k <=
+    batch``, as block reads and writes: the wave's slots lie in two aligned
+    blocks of ``Bk = window_width(batch)`` slots, which need ``2 Bk <= 2n``.
+    Window lane ``q`` holds ticket ``head - off + q``; the same per-lane
+    predicates run over all 2Bk lanes, active for ``off <= q < off + k``,
+    and each changed plane is written back whole-block.  Tickets may wrap
+    past 2^31 (no -1 sentinel).  Returns what ``deq_planes`` returns for
+    ``tickets = head + arange(batch)`` masked to ``lane < k``, bit for bit:
+    (cycles, safes, enqs, idxs, values, ok[, birth stamps])."""
+    idx_botc = idx_bot - 1
+    bk = window_width(batch)
+    (w_c, w_s, w_e, w_i), blocks, off = _window_read(
+        (cycles, safes, enqs, idxs), head, bk, nslots_log2)
+    q = jnp.arange(2 * bk, dtype=jnp.int32)
+    active = (q >= off) & (q < off + k)
+    c = ticket_cycle(head - off + q, nslots_log2)
+    empty = (w_i == idx_bot) | (w_i == idx_botc)
+    flag = (w_e & 1) if birth_packed else w_e
+    hit = active & (w_c == c) & (~empty) & (flag == 1)
+    older = cycle_lt(w_c, c, nslots_log2)
+    adv = active & (~hit) & empty & older
+    uns = active & (~hit) & (~empty) & older
+    idxs = _window_write(idxs, jnp.where(hit, idx_botc, w_i), blocks)
+    cycles = _window_write(cycles, jnp.where(adv, c, w_c), blocks)
+    safes = _window_write(safes, jnp.where(uns, 0, w_s), blocks)
+
+    def lanes(x):                        # dequeue lanes are in ticket order
+        return jax.lax.dynamic_slice(x, (off,), (batch,))
+
+    out = (cycles, safes, enqs, idxs, lanes(jnp.where(hit, w_i, -1)),
+           lanes(hit.astype(jnp.int32)))
+    if birth_packed:
+        out += (lanes(jnp.where(hit, w_e >> 1, -1)),)
+    return out
+
+
+@jax.named_scope("repro.ring.enq")
+def enq_window(cycles, safes, enqs, idxs, tail, head, values, count, *,
+               nslots_log2: int, idx_bot: int, birth_round=None):
+    """``enq_planes`` for the wave of tickets ``tail + [0, count)`` whose
+    values are ``values[:count]`` in ticket (rank) order, as block reads
+    and writes over ``Bk = window_width(len(values))`` slots (``2 Bk <=
+    2n``; see ``deq_window``).  Cycle, safe bit and flag of a window lane
+    follow from its ticket ``tail - off + q``; only the payload plane takes
+    ``values``, shifted to the window offset.  ``birth_round`` packs the
+    stamp into the flag as in ``enq_planes``.  Returns (cycles, safes,
+    enqs, idxs, ok) with ``ok`` in rank order, bit for bit what
+    ``enq_planes`` returns for ``tickets = tail + arange(len(values))``
+    masked to ``lane < count``."""
+    idx_botc = idx_bot - 1
+    width = values.shape[0]
+    bk = window_width(width)
+    (w_c, w_s, w_e, w_i), blocks, off = _window_read(
+        (cycles, safes, enqs, idxs), tail, bk, nslots_log2)
+    q = jnp.arange(2 * bk, dtype=jnp.int32)
+    active = (q >= off) & (q < off + count)
+    t = tail - off + q
+    c = ticket_cycle(t, nslots_log2)
+    empty = (w_i == idx_bot) | (w_i == idx_botc)
+    can = active & cycle_lt(w_c, c, nslots_log2) & empty & (
+        (w_s == 1) | ((t - head) >= 0))
+    v = jax.lax.dynamic_update_slice(jnp.zeros((2 * bk,), jnp.int32),
+                                     values.astype(jnp.int32), (off,))
+    cycles = _window_write(cycles, jnp.where(can, c, w_c), blocks)
+    safes = _window_write(safes, jnp.where(can, 1, w_s), blocks)
+    enqs = _window_write(enqs, jnp.where(can, _install_flag(birth_round),
+                                         w_e), blocks)
+    idxs = _window_write(idxs, jnp.where(can, v, w_i), blocks)
+    ok = jax.lax.dynamic_slice(can.astype(jnp.int32), (off,), (width,))
+    return cycles, safes, enqs, idxs, ok
 
 
 def _enq_kernel(nslots_log2, idx_bot, head_ref, tickets_ref, values_ref,

@@ -9,19 +9,28 @@ ticket → enqueue cycle inside ONE jitted ``lax.while_loop``
 (``enginecore.fused_loop``):
 
 * head/tail (ring) and size (heap) are device scalars in the loop carry;
-* the dequeue wave is the vectorized ``deq_planes`` gather/scatter;
+* the dequeue wave consumes the tickets ``head + [0, k)`` in one
+  vectorized update;
 * child tickets come from the ``wavefaa`` kernel over the spawn mask — the
   in-loop leader-FAA of paper Alg. 1 — instead of host ticket math;
-* the enqueue wave installs ALL children in one vectorized ``enq_planes``
-  scatter (the legacy path chunks them into ``batch``-sized dispatches);
+* the enqueue wave installs ALL children in one vectorized update (the
+  legacy path chunks them into ``batch``-sized dispatches);
 * the host syncs only at quiescence, or every ``sync_every`` rounds when
   the caller wants a stats heartbeat.
 
 Kernel faces: ``wavefaa`` is the one Pallas kernel in a round.  The ring,
-heap and compaction waves run as their pure-jnp plane functions
-(``enq_planes``/``deq_planes``, ``heap_planes``, ``compact_planes``) on
-every backend — XLA places the planes in HBM, where the Pallas twins kept
-the whole ring or heap in VMEM and the v5e compiler refuses them.
+heap and compaction waves run as pure-jnp plane functions on every
+backend — XLA places the planes in HBM, where the Pallas twins kept the
+whole ring or heap in VMEM and the v5e compiler refuses them.  Both ring
+waves are contiguous ticket runs, so ``RingEngine`` runs them as window
+waves (``deq_window``/``enq_window``: two aligned block reads and writes
+a plane, no per-lane scatter), the children first put in ticket order by
+``_rank_order`` (a compacted dense wave already is), and ``wavefaa``'s new
+counter giving their count.  The windows always fit: a dequeue batch is
+at most the capacity, and of a child wave only the first ``capacity``
+children in rank order can ever install, so the enqueue window takes at
+most that many.  ``stats["ring_window_waves"]`` counts the window waves,
+two a round.
 
 Overflow and ``max_rounds`` truncation cannot raise from traced code, so
 the loop carries an overflow flag, exits early, and the host driver raises
@@ -30,8 +39,9 @@ legacy path, one sync later.
 
 Bit-determinism: within a round the fused engine issues exactly the
 tickets the legacy loop issues (wavefaa ranks = row-major compaction
-order, Lemma III.1), applies them through the same vectorized plane
-updates, and calls the same jitted ``step_fn`` on the same operands — so
+order, Lemma III.1), applies them through plane updates that are
+bit-identical to the legacy loop's scatter waves, and calls the same
+jitted ``step_fn`` on the same operands — so
 acc, field planes, head/tail, and stats counters are bit-identical to the
 legacy loop (tests assert this on BFS, raytrace, and tree workloads).
 Each engine here contributes only its ``_round`` body and plane
@@ -51,7 +61,8 @@ from ..kernels.compact import compact_planes, compact_width
 from ..kernels.heap_batch import (KEY_INF as HEAP_KEY_INF, OP_DELMIN,
                                   OP_INSERT, OP_NOP, heap_planes)
 from ..kernels.pallas_env import resolve_interpret
-from ..kernels.ring_slots import deq_planes, enq_planes
+from ..kernels.ring_slots import (deq_planes, deq_window, enq_planes,
+                                  enq_window)
 from ..kernels.wavefaa import LANES, wavefaa
 from ..obs.spans import Spans, span_record, span_tick
 from ..obs.trace import Telemetry, masked_min_max
@@ -130,6 +141,15 @@ def _pad_lanes(mask: jax.Array) -> jax.Array:
     return jnp.zeros((npad,), jnp.int32).at[:n].set(mask)
 
 
+def _rank_order(mask: jax.Array, vals: jax.Array) -> jax.Array:
+    """``vals`` with the lanes ``mask`` keeps first, in lane order — the
+    row-major ranks ``wavefaa`` gives their tickets — by a stable sort on
+    the inverted mask.  The lanes past the popcount are left over."""
+    _, out = jax.lax.sort((jnp.where(mask, 0, 1).astype(jnp.int32), vals),
+                          num_keys=1, is_stable=True)
+    return out
+
+
 class RingEngine(EngineCore):
     """The FIFO megaround configuration: chip ring planes + device
     head/tail scalars under the core's fused loop.  Same contract as the
@@ -170,15 +190,13 @@ class RingEngine(EngineCore):
         batch, capacity = self.batch, self.capacity
         nslots_log2, interp = self.nslots_log2, self.interpret
         sps = sp is not None
-        lane = jnp.arange(batch, dtype=jnp.int32)
         cyc, saf, enq, idx, head, tail = st
         k = jnp.minimum(jnp.int32(batch), tail - head)
-        dtickets = jnp.where(lane < k, head + lane, -1)
         # with spans the dequeue runs in packed-flag mode: the birth stamp
         # lives in the high bits of the enq-flag plane, so it rides the
-        # flag gather/scatter the round already pays for — zero extra
+        # flag read/write the round already pays for — zero extra
         # ops, zero extra carry (measured in DESIGN.md § 7.6)
-        deq = deq_planes(cyc, saf, enq, idx, dtickets,
+        deq = deq_window(cyc, saf, enq, idx, head, k, batch=batch,
                          nslots_log2=nslots_log2, idx_bot=IDX_BOT,
                          birth_packed=sps)
         cyc, saf, enq, idx, vals = deq[:5]
@@ -193,32 +211,29 @@ class RingEngine(EngineCore):
         # decision is static (trace-time) so exactly one path compiles
         wdth = compact_width(cv.shape[0], capacity, self.compact)
         if wdth is None:
-            # in-loop leader FAA: child tickets from the spawn-mask ballot
-            etickets, newctr = wavefaa(_pad_lanes(cm.astype(jnp.int32)),
-                                       jnp.reshape(tail, (1,)),
-                                       interpret=interp)
-            etickets = etickets[:cv.shape[0]]
+            # in-loop leader FAA: the child count from the spawn-mask
+            # ballot; its tickets are the run tail + rank, so the window
+            # takes the children in rank order, and no more than the
+            # capacity, as past it none installs (``over``)
+            _, newctr = wavefaa(_pad_lanes(cm.astype(jnp.int32)),
+                                jnp.reshape(tail, (1,)), interpret=interp)
             n_child = newctr[0] - tail
-            over = (tail + n_child - head) > capacity
-            etickets = jnp.where(over, -1, etickets)  # suppress install
+            with jax.named_scope("repro.ring.enq"):
+                cv = _rank_order(cm, cv)[:capacity]
         else:
             # compaction subsumes the ballot: the dense wave IS the
-            # children in wavefaa rank order, so tickets are the
-            # contiguous run tail + [0, n_child) — bit-identical
-            # (ticket, value) scatters to the sparse install
+            # children in wavefaa rank order, the contiguous tickets
+            # tail + [0, n_child)
             with jax.named_scope("repro.ring.enq"):
                 (cv,), n_child = compact_planes(cm.astype(jnp.int32), (cv,),
                                                 width=wdth)
-            over = (tail + n_child - head) > capacity
-            lane_w = jnp.arange(wdth, dtype=jnp.int32)
-            etickets = jnp.where((lane_w < n_child) & ~over,
-                                 tail + lane_w, -1)
-        cyc, saf, enq, idx, _ = enq_planes(
-            cyc, saf, enq, idx, etickets, cv, head,
+        over = (tail + n_child - head) > capacity
+        total = jnp.where(over, 0, n_child)
+        cyc, saf, enq, idx, _ = enq_window(
+            cyc, saf, enq, idx, tail, head, cv, total,
             nslots_log2=nslots_log2, idx_bot=IDX_BOT,
             birth_round=sp.round if sps else None)
-        tail = jnp.where(over, tail, tail + n_child)
-        total = jnp.where(over, 0, n_child)
+        tail = tail + total
         telinfo = None
         if tel:
             mn, mx = masked_min_max(vals, ok)      # FIFO: payload extrema
@@ -275,6 +290,7 @@ class RingEngine(EngineCore):
             ext = [self._tel_init(), self._span_init(), None]
         self._run_chunks(state, ext, lambda q: int(q.tail - q.head),
                          "ring", max_rounds)
+        self.stats["ring_window_waves"] = 2 * self.stats["rounds"]
         q, acc = state[0], state[1]
         planes = (q.cycles, q.safes, q.enqs, q.idxs)
         if self.spans is not None:

@@ -21,6 +21,8 @@ process at a time may load the TPU library, and each xdist worker imports
 every test file.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -78,11 +80,72 @@ def test_wavefaa_compiles(one_chip, lanes):
     assert MOSAIC in text
 
 
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*)$")
+_OPERATION = re.compile(r"^([\w-]+)\(([^)]*)\)(.*)$")
+_CALLED = re.compile(r"(?:calls|to_apply|body|condition)=%([\w.-]+)")
+
+
+def _split_shape(text):
+    """``(shape, rest)`` of an instruction's right-hand side; a tuple
+    shape is its whole parenthesised group."""
+    if not text.startswith("("):
+        return text.split(" ", 1)
+    depth = 0
+    for i, ch in enumerate(text):
+        depth += {"(": 1, ")": -1}.get(ch, 0)
+        if depth == 0:
+            return text[:i + 1], text[i + 2:]
+
+
+def _computations(text):
+    """Optimized HLO text as ``{computation: [(name, shape, opcode,
+    operands, attributes)]}``, each array shape without its layout."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line == "}":
+            cur = None
+        elif cur is not None and (m := _INSTRUCTION.match(line)):
+            shape, rhs = _split_shape(m.group(2))
+            op = _OPERATION.match(rhs)
+            if op:
+                cur.append((m.group(1), re.sub(r"\{[^}]*\}", "", shape),
+                            op.group(1),
+                            [a.strip().lstrip("%")
+                             for a in op.group(2).split(",")],
+                            op.group(3)))
+    return comps
+
+
+def _while_body(text, plane):
+    """Every instruction of the while loop that carries ``plane``-typed
+    state, its body and the computations the body calls, transitively."""
+    comps = _computations(text)
+    bodies = [re.search(r"body=%([\w.-]+)", line).group(1)
+              for line in text.splitlines()
+              if " while(" in line and plane in line]
+    assert len(bodies) == 1, bodies
+    seen, todo = set(), bodies
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for *_, rest in comps[c]:
+            todo += _CALLED.findall(rest)
+    return [(c, ins) for c in seen for ins in comps[c]]
+
+
 def test_ring_engine_round_compiles_at_2_24_slots(one_chip):
     """The BFS phase's chip engine: batch 1024, capacity 2^23 (2^24 ring
     slots, 4 planes = 256 MiB).  The ring waves run as XLA ops (the Pallas
     ring kernels would hold the whole ring in VMEM), so the only Mosaic
-    call is ``wavefaa``."""
+    call is ``wavefaa``.  They are window waves: inside the loop no
+    scatter or gather of a ring wave touches a whole plane, each plane is
+    updated in place by block writes, and no plane is copied."""
     from repro.runtime.fusedrounds import RingEngine, RingState
     s = _on(one_chip)
     eng = RingEngine(_fanout_step(16), capacity_log2=23, batch=1024,
@@ -92,8 +155,23 @@ def test_ring_engine_round_compiles_at_2_24_slots(one_chip):
     q = RingState(s((ns,)), s((ns,)), s((ns,)), s((ns,)), s(()), s(()))
     comp = eng._megaround.lower(q, s((17,)), s(()), s(()), s(()),
                                 s(())).compile()
-    assert comp.as_text().count(MOSAIC) == 1
+    text = comp.as_text()
+    assert text.count(MOSAIC) == 1
     assert comp.memory_analysis().argument_size_in_bytes >= 4 * ns * 4
+    plane = f"s32[{ns}]"
+    body = _while_body(text, plane)
+    shapes = {(c, name): shape for c, (name, shape, *_) in body}
+    wave_ops = [name for c, (name, _, op, args, rest) in body
+                if op in ("scatter", "gather")
+                and re.search(r"repro\.ring\.(enq|deq)", rest)
+                and shapes.get((c, args[0])) == plane]
+    assert wave_ops == []
+    copies = [name for _, (name, shape, op, *_) in body
+              if op.startswith("copy") and plane in shape]
+    assert copies == []
+    block_writes = [name for _, (name, shape, op, *_) in body
+                    if op == "dynamic-update-slice" and shape == plane]
+    assert len(block_writes) >= 2 * (3 + 4)   # deq 3 planes, enq 4
 
 
 def test_heap_engine_round_compiles(one_chip):
